@@ -21,6 +21,7 @@ on real hardware); on one device it runs the identical 1x1-tiled code.
 Run:  PYTHONPATH=src python examples/train_yolo_tiled.py --steps 200
 """
 import argparse
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -48,10 +49,10 @@ def main() -> int:
     ap.add_argument("--backend", default="xla", choices=["xla", "pallas"])
     ap.add_argument("--compress", default=None, choices=[None, "int8"])
     ap.add_argument("--lr", type=float, default=1e-3)
-    # new dir name: the unified TrainState checkpoint layout is incompatible
-    # with the pre-refactor {"params","opt","step"} dict checkpoints
-    ap.add_argument("--ckpt-dir", default="/tmp/yolo_tiled_unified_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint directory (default: a fresh temporary one)")
     args = ap.parse_args()
+    ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="yolo_tiled_ckpt_")
 
     depth = len(yolov2_16_layers()[: args.layers])
     if args.group == "auto":
@@ -98,7 +99,7 @@ def main() -> int:
         train_step=step_fn,
         make_batch=make_batch,
         steps=args.steps,
-        cfg=DriverConfig(ckpt_dir=args.ckpt_dir, ckpt_every=50, log_every=25),
+        cfg=DriverConfig(ckpt_dir=ckpt_dir, ckpt_every=50, log_every=25),
     )
     warm = report.step_times[5:] or report.step_times
     print(

@@ -23,7 +23,7 @@ tests/test_spmd.py / ISSUE 7, DESIGN.md §10):
     uninterrupted untiled run to <=1e-5 (params) for every ordered pair.
 """
 import os
-import shutil
+import tempfile
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
@@ -53,8 +53,7 @@ LAYERS = yolov2_16_layers()[:4]
 H = W = 64
 BATCH = 4
 SEED = 0
-TMP = "/tmp/repro_elastic_check"
-shutil.rmtree(TMP, ignore_errors=True)
+TMP = tempfile.mkdtemp(prefix="repro_elastic_check_")
 
 tcfg = TrainConfig(lr=1e-2, optimizer="sgd", warmup=10, steps=100, grad_clip=1.0)
 pcfg = ParallelConfig(grad_accum=1)

@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.halo import (
     EFBag,
@@ -29,9 +28,9 @@ mesh22 = jax.make_mesh((2, 2), ("r", "c"))
 def check_1d():
     x = jnp.arange(8 * 4 * 3, dtype=jnp.float32).reshape(8 * 4, 3)
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda x: halo_exchange_1d(x, 2, 1, "x", dim=0),
-        mesh=mesh1, in_specs=P("x", None), out_specs=P("x", None), check_rep=False,
+        mesh=mesh1, in_specs=P("x", None), out_specs=P("x", None), check_vma=False,
     )
     y = np.asarray(f(x)).reshape(8, 7, 3)           # 4 + 2 + 1 rows per shard
     xs = np.asarray(x).reshape(8, 4, 3)
@@ -51,28 +50,28 @@ def check_packed_1d():
     for mesh, n in ((mesh_pair, 2), (mesh1, 8)):
         x = jnp.arange(n * 4 * 3, dtype=jnp.float32).reshape(n * 4, 3)
         for lo, hi in ((2, 1), (1, 2), (2, 0), (0, 1), (0, 0)):
-            eager = shard_map(
+            eager = jax.shard_map(
                 lambda x: halo_exchange_1d(x, lo, hi, "x", dim=0),
                 mesh=mesh, in_specs=P("x", None), out_specs=P("x", None),
-                check_rep=False,
+                check_vma=False,
             )
             def packed_cat(x, lo=lo, hi=hi):
                 lo_s, hi_s = halo_exchange_1d_packed(x, lo, hi, "x", dim=0)
                 parts = [p for p in (lo_s, x, hi_s) if p.shape[0] > 0]
                 return jnp.concatenate(parts, axis=0)
 
-            packed = shard_map(
+            packed = jax.shard_map(
                 packed_cat,
                 mesh=mesh, in_specs=P("x", None), out_specs=P("x", None),
-                check_rep=False,
+                check_vma=False,
             )
             np.testing.assert_array_equal(np.asarray(eager(x)), np.asarray(packed(x)))
     # the 2-shard both-sides case must lower to exactly ONE ppermute
     jaxpr = jax.make_jaxpr(
-        shard_map(
+        jax.shard_map(
             lambda x: halo_exchange_1d_packed(x, 2, 1, "x", dim=0),
             mesh=mesh_pair, in_specs=P("x", None),
-            out_specs=(P("x", None), P("x", None)), check_rep=False,
+            out_specs=(P("x", None), P("x", None)), check_vma=False,
         )
     )(jnp.zeros((8, 3)))
     assert str(jaxpr).count("ppermute") == 1, str(jaxpr)
@@ -85,10 +84,10 @@ def check_packed_2d():
     x = jnp.arange(8 * 8 * 2, dtype=jnp.float32).reshape(8, 8, 2)
     halo = (1, 2, 2, 1)
 
-    eager = shard_map(
+    eager = jax.shard_map(
         lambda x: halo_exchange_2d(x, halo, "r", "c", dims=(0, 1)),
         mesh=mesh22, in_specs=P("r", "c", None), out_specs=P("r", "c", None),
-        check_rep=False,
+        check_vma=False,
     )
 
     def packed_fn(x):
@@ -96,10 +95,10 @@ def check_packed_2d():
         parts = [p for p in (c_lo, x_rows, c_hi) if p.shape[1] > 0]
         return jnp.concatenate(parts, axis=1)
 
-    packed = shard_map(
+    packed = jax.shard_map(
         packed_fn,
         mesh=mesh22, in_specs=P("r", "c", None), out_specs=P("r", "c", None),
-        check_rep=False,
+        check_vma=False,
     )
     np.testing.assert_array_equal(np.asarray(eager(x)), np.asarray(packed(x)))
     print("packed 2d (corners incl.) ok")
@@ -118,15 +117,15 @@ def check_adjoint():
                 x = jax.random.normal(k1, (n * shard_rows, 3))
                 g = jax.random.normal(k2, (n * (shard_rows + lo + hi), 3))
 
-                H = shard_map(
+                H = jax.shard_map(
                     lambda x, lo=lo, hi=hi: halo_exchange_1d(x, lo, hi, "x", dim=0),
                     mesh=mesh, in_specs=P("x", None), out_specs=P("x", None),
-                    check_rep=False,
+                    check_vma=False,
                 )
-                Ht = shard_map(
+                Ht = jax.shard_map(
                     lambda y, lo=lo, hi=hi: send_boundary_sum_1d(y, lo, hi, "x", dim=0),
                     mesh=mesh, in_specs=P("x", None), out_specs=P("x", None),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 lhs = float(jnp.vdot(H(x), g))
                 rhs = float(jnp.vdot(x, Ht(g)))
@@ -155,10 +154,10 @@ def check_wire_codec_adjoint():
     rows, ch, T = 4, 3, 8
     for mesh, n in ((mesh_pair, 2), (mesh1, 8)):
         y = jax.random.normal(jax.random.PRNGKey(3), (n * (rows + lo + hi), ch))
-        exact_f = shard_map(
+        exact_f = jax.shard_map(
             lambda v: send_boundary_sum_1d(v, lo, hi, "x", dim=0),
             mesh=mesh, in_specs=P("x", None), out_specs=P("x", None),
-            check_rep=False,
+            check_vma=False,
         )
         exact = np.asarray(exact_f(y))
         for spec in ("int8", "topk:0.5"):
@@ -172,10 +171,10 @@ def check_wire_codec_adjoint():
                 new_lo, new_hi = bag.emitted
                 return out, new_lo, new_hi
 
-            stepped = shard_map(
+            stepped = jax.shard_map(
                 step_fn, mesh=mesh,
                 in_specs=(P("x", None),) * 3,
-                out_specs=(P("x", None),) * 3, check_rep=False,
+                out_specs=(P("x", None),) * 3, check_vma=False,
             )
             res_lo = jnp.zeros((n * lo, ch))
             res_hi = jnp.zeros((n * hi, ch))
@@ -209,10 +208,10 @@ def check_wire_codec_adjoint():
 def check_2d():
     x = jnp.arange(16 * 8 * 2, dtype=jnp.float32).reshape(16, 8, 2)
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda x: halo_exchange_2d(x, (1, 1, 1, 1), "r", "c", dims=(0, 1)),
         mesh=mesh2, in_specs=P("r", "c", None), out_specs=P("r", "c", None),
-        check_rep=False,
+        check_vma=False,
     )
     y = np.asarray(f(x))
     # global reassembly: each (4+2, 4+2) tile must equal the zero-padded

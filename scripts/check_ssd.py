@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.mamba2 import _ssd_chunk_scan
 
@@ -60,12 +59,12 @@ def sharded(xs, dts, Bs, Cs):
     return y
 
 
-f = shard_map(
+f = jax.shard_map(
     sharded,
     mesh=mesh,
     in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp"), P(None, "sp")),
     out_specs=P(None, "sp"),
-    check_rep=False,
+    check_vma=False,
 )
 y_sp = jax.jit(f)(x, dt, B, C)
 y_ref, _ = naive_ssm(x, dt, A, B, C)

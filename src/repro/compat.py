@@ -1,14 +1,10 @@
-"""Small jax version/platform compat shims (the container pins an older jax).
+"""Small platform helpers shared across the repo.
 
-Centralised so every module spells compat the same way:
   - ``keystr_slash``: bare-name, slash-separated key paths
-    (``params/0/moe/w_gate``) on every jax version.  Newer jax spells this
-    ``keystr(path, simple=True, separator="/")``; older jax has neither
-    kwarg, so join the raw key entries by hand in the identical format.
-    The output is load-bearing: checkpoint manifests (ckpt/manager.py) and
-    the sharding-rule substring patterns (parallel/sharding.py, e.g.
-    ``"moe/w_gate"``) both key on this exact spelling, so it must not vary
-    with the installed jax.
+    (``params/0/moe/w_gate``).  The output is load-bearing: checkpoint
+    manifests (ckpt/manager.py) and the sharding-rule substring patterns
+    (parallel/sharding.py, e.g. ``"moe/w_gate"``) both key on this exact
+    spelling.
   - ``overlap_supported`` / ``enable_overlap_xla_flags``: whether the
     active backend can actually hide collectives behind compute, and the
     XLA flags that make it do so.  The overlap schedule only pays off with
@@ -16,11 +12,14 @@ Centralised so every module spells compat the same way:
     CPU backend runs collectives inline, which is why overlap *measures*
     slower than sync there (BENCH_tiled.json overhead 1.06-1.12) despite
     modeling faster - ``schedule="auto"`` gates on this.
-(``core.halo.axis_size`` is the shard_map-side shim for ``lax.axis_size``.)
+  - ``enable_compile_cache``: JAX's persistent compilation cache, in the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names or else in one fixed
+    git-ignored directory of the checkout.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 from jax.tree_util import keystr
 
@@ -61,16 +60,26 @@ def enable_overlap_xla_flags(env=None) -> list[str]:
     return added
 
 
+#: The compile cache's home when ``JAX_COMPILATION_CACHE_DIR`` is unset: one
+#: fixed path in the checkout (listed in .gitignore), so every run of this
+#: checkout finds what earlier runs compiled.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set here; otherwise the cache goes to
+    ``COMPILE_CACHE_DIR``.  Call before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
 def keystr_slash(path) -> str:
-    try:
-        return keystr(path, simple=True, separator="/")
-    except TypeError:
-        parts = []
-        for k in path:
-            for attr in ("key", "idx", "name"):
-                if hasattr(k, attr):
-                    parts.append(str(getattr(k, attr)))
-                    break
-            else:
-                parts.append(str(k))
-        return "/".join(parts)
+    return keystr(path, simple=True, separator="/")

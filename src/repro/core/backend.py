@@ -41,8 +41,9 @@ Contract (DESIGN.md §4):
 
 ``xla`` (default) lowers to ``lax.conv_general_dilated``.  ``pallas`` runs
 the direct MXU kernel in ``kernels/conv2d_tiled`` - forward AND backward -
-compiled on TPU, interpret-mode everywhere else so CI exercises the same
-code path on CPU.
+in interpret mode on the CPU backend (so CI exercises the same code path)
+and compiled everywhere else: on an accelerator the kernel compiles or
+raises, never silently interprets (``pallas_interpret``).
 """
 from __future__ import annotations
 
@@ -181,6 +182,11 @@ register_conv_backend("xla", _xla_conv, fused_acts=tuple(ACTIVATIONS))
 # ---------------------------------------------------------------------------
 
 
+def pallas_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode: on the CPU backend only."""
+    return jax.default_backend() == "cpu"
+
+
 def _pallas_conv(
     x, w, b, *, stride: int, act: str, block_oh: int | None = None
 ) -> jax.Array:
@@ -193,8 +199,7 @@ def _pallas_conv(
         # precision (bf16 activations, fp32 filters) the epilogue must add
         # the bias at the promoted precision, matching the xla backend.
         b = jnp.zeros((w.shape[-1],), jnp.result_type(x.dtype, w.dtype))
-    interpret = jax.default_backend() != "tpu"
-    return conv2d(x, w, b, stride, 0, act, interpret, block_oh)
+    return conv2d(x, w, b, stride, 0, act, pallas_interpret(), block_oh)
 
 
 register_conv_backend("pallas", _pallas_conv, fused_acts=("linear", "relu", "leaky"))

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.tiling import (
     Group,
@@ -47,7 +46,6 @@ from repro.core.tiling import (
 from repro.core.halo import (
     EFBag,
     WireCtx,
-    axis_size,
     halo_exchange_2d,
     halo_exchange_2d_ragged,
     halo_exchange_2d_spec,
@@ -988,7 +986,7 @@ def _global_batch(
         return batch_global
     if batch_axis is None:
         return local_batch
-    return local_batch * axis_size(batch_axis)
+    return local_batch * lax.axis_size(batch_axis)
 
 
 def apply_stack_local(
@@ -1524,12 +1522,12 @@ def make_tiled_forward(
             )
         return local(params, x)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(), aspec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     if plan.is_uniform:
         return mapped
@@ -1670,12 +1668,12 @@ def make_tiled_loss(
             s, c = local(params, xs, ts)
             return lax.psum(s, axes) / lax.psum(c, axes)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             pfn,
             mesh=mesh,
             in_specs=(P(), P(None, None, row_axis, col_axis, None), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
 
         def loss(params, x, target):
@@ -1723,12 +1721,12 @@ def make_tiled_loss(
         c = lax.psum(c, axes)
         return s / c
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(), aspec, tspec),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def loss(params, x, target):
@@ -1800,12 +1798,12 @@ def make_deferred_grad_step(
             loss = lax.psum(s_tot, pipe_axes) / cnt_g
             return loss, grads
 
-        pmapped = shard_map(
+        pmapped = jax.shard_map(
             pfn,
             mesh=mesh,
             in_specs=(P(), P(None, None, row_axis, col_axis, None), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
 
         def pstep(params, xs, ts):
@@ -1924,12 +1922,12 @@ def make_deferred_grad_step(
             loss = lax.psum(loss_sum, tile_axes) / cnt_g
             return loss, grads
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(), aspec, tspec),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     def step(params, xs, ts):

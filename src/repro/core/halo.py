@@ -26,18 +26,6 @@ from jax import lax
 from repro.optim.compression import WireCodec, ef_encode
 
 
-def axis_size(axis_name: str) -> int:
-    """Size of a named mesh axis, callable inside shard_map.
-
-    ``lax.axis_size`` only exists on newer jax; ``psum`` of the literal 1 is
-    the portable spelling (constant-folded to the axis size at trace time).
-    """
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def _shift_perm(n: int, direction: int) -> list[tuple[int, int]]:
     """Permutation sending shard i -> i+direction (no wraparound: edge tiles
     simply receive zeros, which matches SAME zero padding)."""
@@ -176,7 +164,7 @@ def halo_exchange_1d(
 
     Returns an array whose ``dim`` extent is ``x.shape[dim]+halo_lo+halo_hi``.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     parts = []
     if halo_lo > 0:
         # strip the *previous* shard must send us: its last halo_lo rows
@@ -243,7 +231,7 @@ def halo_exchange_1d_packed(
     the per-axis extent of the paper's 2x2 testbed meshes, where the packed
     path halves the collectives per group input from 4 to 2.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1 or (halo_lo == 0 and halo_hi == 0):
         return _zeros_strip(x, halo_lo, dim), _zeros_strip(x, halo_hi, dim)
     if n == 2 and halo_lo > 0 and halo_hi > 0:
@@ -349,7 +337,7 @@ def halo_exchange_1d_ragged(
     uniform exchange.  Requires min(sizes) >= max(halo_lo, halo_hi), checked
     at plan time (``build_stack_plan``).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     smax = max(sizes)
     if x.shape[dim] != smax:
         raise ValueError(
@@ -453,7 +441,7 @@ def halo_exchange_1d_spec(
     """
     from repro.core.tiling import dedup_axis_shapes
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     smax = max(sizes)
     if x.shape[dim] != smax:
         raise ValueError(
@@ -555,7 +543,7 @@ def send_boundary_sum_1d(
     residual is pushed to ``wire.bag.emitted`` (eager - there is no AD pass
     here to smuggle it through), in the same order the bag was drawn from.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     core_lo, core_hi = overlap_lo, x.shape[dim] - overlap_hi
     core = lax.slice_in_dim(x, core_lo, core_hi, axis=dim)
 

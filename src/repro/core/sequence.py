@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.halo import axis_size, halo_exchange_1d, _shift_perm
+from repro.core.halo import halo_exchange_1d, _shift_perm
 
 
 # ---------------------------------------------------------------------------
@@ -85,7 +85,7 @@ def seq_scan_combine(
     this is the sequence-parallel analogue of the paper's group-boundary
     exchange, with O(shards) scalars instead of O(map) activations.
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     decays = lax.all_gather(decay, axis)          # (n, ...) leading shard dim
     states = lax.all_gather(state, axis)          # (n, ...)
@@ -117,7 +117,7 @@ def seq_scan_combine_hops(
     rounds each shard holds the *inclusive* prefix; one final +1 hop converts
     to the exclusive prefix (the incoming state).
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     d, s = decay, state
     dx = d.reshape(d.shape + (1,) * (s.ndim - d.ndim))   # broadcast over state

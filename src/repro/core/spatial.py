@@ -35,7 +35,6 @@ from jax import lax
 from repro.core.tiling import ConvSpec
 from repro.core.halo import (
     WireCtx,
-    axis_size,
     halo_exchange_2d,
     halo_exchange_1d_packed,
 )
@@ -338,16 +337,26 @@ def _core_mask(
     return (rmask[:, None] & cmask[None, :]).astype(jnp.float32)
 
 
+def _masked_batch_stats(y, mask, axes, n_global):
+    """Per-channel batch mean and variance over the masked (owned)
+    positions of every tile: one psum for the mean, a second over values
+    centred on it - the reference's formulation.  The one-pass
+    E[y^2] - E[y]^2 is exact in real arithmetic, but in fp32 its backward
+    cancels large terms and adds an error of eps * mean * n to each
+    position's cotangent; summed over a 416x416 map by the next wgrad,
+    that error swamps the weight gradient."""
+    mean = lax.psum(jnp.sum(y * mask, axis=(0, 1, 2)), axes) / n_global
+    var = lax.psum(jnp.sum(jnp.square((y - mean) * mask), axis=(0, 1, 2)), axes) / n_global
+    return mean, var
+
+
 def _bn_tiled(y, layer, params, core_halo, tile_axes, n_global):
     """Exact cross-tile batch norm: statistics over core (owned) positions
     only - overlap/halo regions are duplicated across tiles and must not be
     double counted - reduced with psum over the tile axes."""
     ext_h, ext_w = y.shape[1], y.shape[2]
     mask = _core_mask(ext_h, ext_w, core_halo)[None, :, :, None]
-    s = lax.psum(jnp.sum(y * mask, axis=(0, 1, 2)), tile_axes)
-    ss = lax.psum(jnp.sum(jnp.square(y) * mask, axis=(0, 1, 2)), tile_axes)
-    mean = s / n_global
-    var = ss / n_global - jnp.square(mean)
+    mean, var = _masked_batch_stats(y, mask, tile_axes, n_global)
     return _bn_apply(y, mean, var, params["bn_scale"], params["bn_bias"])
 
 
@@ -653,11 +662,7 @@ def apply_layer_local_ragged(
             if batch_axis is not None:
                 bn_axes = (batch_axis,) + bn_axes
             mask = _core_mask_ragged(y.shape[1], y.shape[2], out_halo, out_size)
-            mask = mask[None, :, :, None]
-            s = lax.psum(jnp.sum(y * mask, axis=(0, 1, 2)), bn_axes)
-            ss = lax.psum(jnp.sum(jnp.square(y) * mask, axis=(0, 1, 2)), bn_axes)
-            mean = s / n_global
-            var = ss / n_global - jnp.square(mean)
+            mean, var = _masked_batch_stats(y, mask[None, :, :, None], bn_axes, n_global)
             y = _bn_apply(y, mean, var, params["bn_scale"], params["bn_bias"])
     if not fused:
         y = _ACTIVATIONS[layer.act](y)
@@ -738,6 +743,10 @@ def apply_layer_local_spec(
     bn_stats = bn and not inference
     from repro.core.halo import _switch_by_size
 
+    def _spec_core(a, vout_r, vout_c):
+        top, bottom, left, right = out_halo
+        return a[:, top:vout_r - bottom, left:vout_c - right, :]
+
     def mk(io):
         (vin_r, vin_c), (vout_r, vout_c) = io
 
@@ -751,12 +760,7 @@ def apply_layer_local_spec(
                 )
             outs = []
             if bn_stats:
-                top, bottom, left, right = out_halo
-                core = y[:, top:vout_r - bottom, left:vout_c - right, :]
-                outs = [
-                    jnp.sum(core, axis=(0, 1, 2)),
-                    jnp.sum(jnp.square(core), axis=(0, 1, 2)),
-                ]
+                outs = [jnp.sum(_spec_core(y, vout_r, vout_c), axis=(0, 1, 2))]
             pad = [
                 (0, 0),
                 (0, canon_out_hw[0] - vout_r),
@@ -775,15 +779,23 @@ def apply_layer_local_spec(
     else:
         fused = (not layer.batch_norm) and layer.act in get_conv_backend(backend).fused_acts
     if bn_stats:
-        y, s, ss = res
+        # centred two-pass statistics, as in _masked_batch_stats; each
+        # branch sums over its own static core window
+        y, s = res
         n_global = batch_global * map_out_hw[0] * map_out_hw[1]
         bn_axes = (row_axis, col_axis)
         if batch_axis is not None:
             bn_axes = (batch_axis,) + bn_axes
-        s = lax.psum(s, bn_axes)
-        ss = lax.psum(ss, bn_axes)
-        mean = s / n_global
-        var = ss / n_global - jnp.square(mean)
+        mean = lax.psum(s, bn_axes) / n_global
+
+        def mk_ss(io):
+            (_, _), (vout_r, vout_c) = io
+            return lambda a: jnp.sum(
+                jnp.square(_spec_core(a, vout_r, vout_c) - mean), axis=(0, 1, 2)
+            )
+
+        ss = _switch_by_size(branch, [mk_ss(io) for io in branch_io], y)
+        var = lax.psum(ss, bn_axes) / n_global
         y = _bn_apply(y, mean, var, params["bn_scale"], params["bn_bias"])
     else:
         y = res
@@ -818,7 +830,7 @@ def _wire_all_gather(x: jax.Array, axis_name: str, dim: int, wire: WireCtx | Non
     and summed on the receiver (DESIGN.md §12)."""
     if wire is None:
         return lax.all_gather(x, axis_name, axis=dim, tiled=True)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     codec = wire.codec
     res = tuple(wire.bag.take(x.shape) for _ in range(n))
     xshape, xdtype = tuple(x.shape), x.dtype   # trace constants, closed over
@@ -887,8 +899,8 @@ def reshard_spatial_to_data(
     clear message otherwise (pick batch/grad_accum so each microbatch
     spreads over the tile grid).
     """
-    n = axis_size(row_axis)
-    m = axis_size(col_axis)
+    n = lax.axis_size(row_axis)
+    m = lax.axis_size(col_axis)
     x = _wire_all_gather(x, row_axis, dims[0], wire)
     x = _wire_all_gather(x, col_axis, dims[1], wire)
     return _batch_block_slice(x, row_axis, col_axis, n, m)

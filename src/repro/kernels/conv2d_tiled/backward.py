@@ -10,7 +10,7 @@ exactly like the forward one:
   (``w_rot[u, v, co, ci] = w[K-1-u, K-1-v, ci, co]``).  That is *the same
   compute shape as the forward pass*, so ``conv2d_dgrad_tile`` reuses the
   forward Pallas kernel (``kernel.conv2d_tile``) verbatim - including its
-  OH-block spatial blocking and the 1 MiB VMEM accumulator budget - on the
+  OH-block row-slab streaming and its VMEM budgets - on the
   transformed operands.  The dilation/rotation are pure data movement
   (``lax.pad`` with interior padding, a reverse and a transpose); every MAC
   runs on the MXU path.
@@ -22,12 +22,12 @@ exactly like the forward one:
                                          * g[n, oh, ow, co]
 
   ``conv2d_wgrad_tile`` runs a dedicated kernel with grid
-  ``(Cout/bc, K, K)`` - Cout-block major so one cotangent slab stays
-  resident in VMEM across the K² minor sweep - and reduces each tap to ONE
-  (OH·OW, Cin)ᵀ·(OH·OW, bc) MXU matmul per batch element, accumulated in
-  fp32.  The per-grid-cell accumulator is a single (Cin, bc) filter slab,
-  so wgrad never scales with the spatial extent the way a forward
-  accumulator would.  The kernel produces the *per-tile partial sum*; the
+  ``(Cout/bc, N, OH blocks)``: it streams the same halo'd row slabs of the
+  column-folded input as the forward kernel, next to the cotangent rows of
+  that block, and reduces each ki to ONE (rows, K·Cin)ᵀ·(rows, bc) MXU
+  matmul into a resident fp32 (K, K·Cin, bc) filter slab - so neither the
+  input nor the cotangent is ever whole in VMEM, and the accumulator does
+  not scale with the spatial extent.  The kernel produces the *per-tile partial sum*; the
   cross-tile summation is the deferred psum inserted by shard_map
   transposition (paper's deferred weight aggregation).
 
@@ -45,7 +45,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.kernels.conv2d_tiled.kernel import conv2d_tile
+from repro.kernels.conv2d_tiled.kernel import (
+    SUBLANES,
+    auto_block_oh,
+    conv2d_tile,
+    fit_axis,
+    fold_cols,
+    round_up,
+    space_to_depth,
+)
 
 
 def rotate_filter(w: jax.Array) -> jax.Array:
@@ -93,35 +101,24 @@ def conv2d_dgrad_tile(
     )
 
 
-def _wgrad_kernel(
-    x_ref,                       # (N, H, W, Cin) the whole padded input tile
-    g_ref,                       # (N, OH, OW, bc) one Cout slab of the cotangent
-    o_ref,                       # (1, 1, Cin, bc) one (ki, kj) filter slab
-    *,
-    stride: int,
-    oh: int,
-    ow: int,
-    n: int,
-):
-    ki = pl.program_id(1)
-    kj = pl.program_id(2)
-    cin = x_ref.shape[-1]
+def _wgrad_kernel(x_ref, g_ref, o_ref, *, kernel: int, block_oh: int):
+    # The (K, K*Cin, bc) output block is resident across the (n, oh-block)
+    # reduction sweep and doubles as the fp32 accumulator.
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    ow_p, kc = x_ref.shape[-2:]
     bc = g_ref.shape[-1]
-    rows = stride * (oh - 1) + 1
-    cols = stride * (ow - 1) + 1
-    acc = jnp.zeros((cin, bc), jnp.float32)
-    for nn in range(n):
-        xb = x_ref[nn, pl.ds(ki, rows), pl.ds(kj, cols)]       # (rows, cols, Cin)
-        if stride > 1:
-            xb = lax.slice(xb, (0, 0, 0), (rows, cols, cin), (stride, stride, 1))
-        gs = g_ref[nn]                                         # (OH, OW, bc)
-        acc += lax.dot_general(
-            xb.reshape(oh * ow, cin).astype(jnp.float32),
-            gs.reshape(oh * ow, bc).astype(jnp.float32),
+    gs = g_ref[...].astype(jnp.float32).reshape(block_oh * ow_p, bc)
+    for ki in range(kernel):
+        xs = x_ref[ki:ki + block_oh].astype(jnp.float32).reshape(block_oh * ow_p, kc)
+        o_ref[ki] += lax.dot_general(
+            xs, gs,
             (((0,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
-    o_ref[0, 0] = acc.astype(o_ref.dtype)
 
 
 def conv2d_wgrad_tile(
@@ -136,33 +133,54 @@ def conv2d_wgrad_tile(
 ) -> jax.Array:
     """Per-tile weight-gradient partial sum: (K, K, Cin, Cout).
 
-    Grid (Cout/bc, K, K) - Cout-block major so the (N, OH, OW, bc) cotangent
-    slab loads once per Cout block and is reused across all K² taps; the
-    input tile is resident for the whole sweep (same VMEM-scale working-set
-    assumption as the forward kernel).  fp32 accumulation; the output dtype
-    defaults to the promoted input/cotangent dtype so mixed-precision
-    (bf16 activations, fp32 filters) callers pass ``out_dtype=w.dtype``.
+    Same operand layout as the forward kernel: the input is folded to
+    (N, H, OW_p, K*Cin) (strides via space-to-depth first) and streamed as
+    halo'd row slabs, one per OH block; the cotangent block of the same
+    output rows multiplies each of the K row-shifted slabs in one
+    (rows, K*Cin)^T x (rows, bc) matmul.  Grid (Cout/bc, N, OH blocks),
+    with N and the OH blocks a reduction into one resident fp32 filter
+    slab.  Cotangent padding (OW_p, OH blocks) is zeros, so padded columns
+    and rows add nothing.  The output dtype defaults to the promoted
+    input/cotangent dtype, so mixed-precision (bf16 activations, fp32
+    filters) callers pass ``out_dtype=w.dtype``.
     """
-    n, h, wdt, cin = x.shape
     _, oh, ow, cout = g.shape
-    k = kernel
+    cin = x.shape[-1]
     if out_dtype is None:
         out_dtype = jnp.result_type(x.dtype, g.dtype)
+    k = kernel
+    if stride > 1:
+        x = space_to_depth(x, kernel, stride, oh, ow)
+        k = -(-kernel // stride)
+    n = x.shape[0]
+    kc = k * x.shape[-1]
     bc = min(bc, cout)
-    cout_p = -(-cout // bc) * bc
-    if cout_p != cout:
-        g = jnp.pad(g, ((0, 0), (0, 0), (0, 0), (0, cout_p - cout)))
+    cout_p = round_up(cout, bc)
+    ow_p = round_up(ow, SUBLANES)
+    block_oh = auto_block_oh(oh, ow_p, kc, bc, k)
+    n_oh_blocks = -(-oh // block_oh)
+    oh_p = n_oh_blocks * block_oh
+    xc = fold_cols(fit_axis(x, 1, oh_p + k - 1), k, ow_p)
+    g = fit_axis(fit_axis(fit_axis(g, 1, oh_p), 2, ow_p), 3, cout_p)
 
-    kernel_fn = functools.partial(_wgrad_kernel, stride=stride, oh=oh, ow=ow, n=n)
+    kernel_fn = functools.partial(_wgrad_kernel, kernel=k, block_oh=block_oh)
     out = pl.pallas_call(
         kernel_fn,
-        grid=(cout_p // bc, k, k),
+        grid=(cout_p // bc, n, n_oh_blocks),
         in_specs=[
-            pl.BlockSpec((n, h, wdt, cin), lambda co, ki, kj: (0, 0, 0, 0)),
-            pl.BlockSpec((n, oh, ow, bc), lambda co, ki, kj: (0, 0, 0, co)),
+            pl.BlockSpec(
+                (None, pl.Element(block_oh + k - 1), pl.Element(ow_p), pl.Element(kc)),
+                lambda co, i, ob: (i, ob * block_oh, 0, 0),
+            ),
+            pl.BlockSpec((None, block_oh, ow_p, bc), lambda co, i, ob: (i, ob, 0, co)),
         ],
-        out_specs=pl.BlockSpec((1, 1, cin, bc), lambda co, ki, kj: (ki, kj, 0, co)),
-        out_shape=jax.ShapeDtypeStruct((k, k, cin, cout_p), out_dtype),
+        out_specs=pl.BlockSpec((k, kc, bc), lambda co, i, ob: (0, 0, co)),
+        out_shape=jax.ShapeDtypeStruct((k, kc, cout_p), jnp.float32),
         interpret=interpret,
-    )(x, g)
-    return out[..., :cout]
+    )(xc, g)
+    dw = out[..., :cout].reshape(k, k, kc // k, cout)
+    if stride > 1:
+        # invert s2d_filter: (a, b, (r, s, ci)) -> (S*a + r, S*b + s, ci)
+        dw = dw.reshape(k, k, stride, stride, cin, cout).transpose(0, 2, 1, 3, 4, 5)
+        dw = dw.reshape(k * stride, k * stride, cin, cout)[:kernel, :kernel]
+    return dw.astype(out_dtype)

@@ -3,8 +3,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # ^ MUST precede any jax import: jax locks the device count on first init.
 # The dry-run (and ONLY the dry-run) builds the production meshes out of 512
 # placeholder host devices; smoke tests / benches see the real 1-CPU world.
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_dryrun_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "5")
 
 """Multi-pod dry-run: lower + compile every (architecture x shape x mesh)
 cell, extract memory/cost/collective analysis, and emit one JSON artifact
@@ -32,6 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis.hlo import collective_stats
+from repro.compat import enable_compile_cache
 from repro.analysis.roofline import (
     V5E,
     count_params_cfg,
@@ -364,6 +363,7 @@ def main() -> int:
     ap.add_argument("--suffix", default="", help="artifact filename suffix (layout experiments)")
     ap.add_argument("--list", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cells = []
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]

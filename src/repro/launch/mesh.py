@@ -7,17 +7,11 @@ constants, so importing never touches jax device state.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _make(shape, axes) -> Mesh:
-    try:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    except ImportError:
-        # older jax: no AxisType / axis_types kwarg; meshes are Auto already
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.compat import enable_compile_cache
 from repro.models.registry import ARCH_IDS, get_arch
 from repro.serve.engine import Request, ServeEngine
 
@@ -113,7 +114,7 @@ def main() -> int:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     # --cnn mode
-    ap.add_argument("--grid", default="2x2", help="tile grid n x m")
+    ap.add_argument("--grid", default="1x1", help="tile grid n x m (n*m devices)")
     ap.add_argument("--depth", type=int, default=6, help="YOLOv2 prefix depth")
     ap.add_argument("--size", type=int, default=64, help="input H=W")
     ap.add_argument("--backend", choices=("xla", "pallas"), default="xla")
@@ -127,6 +128,7 @@ def main() -> int:
     ap.add_argument("--budget-ms", type=float, default=1000.0)
     ap.add_argument("--ticks", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.crossover is not None and args.crossover != "auto":
         args.crossover = int(args.crossover)
     return _cnn_main(args) if args.cnn else _lm_main(args)

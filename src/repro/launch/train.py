@@ -23,17 +23,20 @@ planner prices its comm terms under the same codec (DESIGN.md §12).
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import tempfile
+from typing import Any, Callable
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ParallelConfig, SHAPES, ShapeConfig, TrainConfig
+from repro.compat import enable_compile_cache
+from repro.configs.base import ParallelConfig, ShapeConfig, TrainConfig
 from repro.data.synthetic import SyntheticStream, place, synth_batch
 from repro.launch.mesh import make_local_mesh, make_production_mesh, make_tile_mesh
 from repro.models.registry import ARCH_IDS, get_arch
 from repro.parallel.api import sharding_ctx
-from repro.runtime.driver import DriverConfig, run_training
+from repro.runtime.driver import DriverConfig, DriverReport, run_training
 from repro.train.trainer import make_train_step
 
 TILED_ARCH = "yolov2-tiled"
@@ -51,7 +54,9 @@ def _add_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--remat", default="full", choices=["none", "full", "dots"])
     ap.add_argument("--compress", default=None, choices=[None, "int8"],
                     help="gradient compression for the weight all-reduce")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint directory (default: a fresh temporary "
+                         "directory, so a rerun never resumes a stale run)")
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--resume", default="auto", choices=["auto", "always", "never"],
@@ -152,7 +157,28 @@ def _resolve_pipeline(spec: str):
         ) from None
 
 
-def _run_tiled(args) -> int:
+@dataclasses.dataclass
+class TiledRun:
+    """What ``run_tiled`` built and did: the planner's arch bundle, the
+    train-step pieces the driver ran, and the driver's report."""
+
+    arch: Any
+    init_state: Callable
+    step_fn: Callable
+    make_batch: Callable
+    report: DriverReport
+
+
+def _driver_config(args) -> DriverConfig:
+    ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="repro_ckpt_")
+    print(f"checkpoints: {ckpt_dir} (resume={args.resume})")
+    return DriverConfig(
+        ckpt_dir=ckpt_dir, ckpt_every=args.ckpt_every,
+        log_every=args.log_every, resume=args.resume,
+    )
+
+
+def run_tiled(args) -> TiledRun:
     from repro.core.grouping import parse_cluster_spec
     from repro.models.yolo import make_yolo_tiled_arch, yolov2_16_layers
 
@@ -201,6 +227,9 @@ def _run_tiled(args) -> int:
         grad_compression=args.compress,
     )
     init_state, train_step = make_train_step(arch, pcfg, tcfg)
+    # state replicated over the tile mesh from the start; batches placed
+    # tile by tile (TiledCNNArch.place_batch) - never gathered on device 0
+    init_state = jax.jit(init_state, out_shardings=arch.state_sharding())
     step_fn = jax.jit(train_step, donate_argnums=(0,))
     tgt = arch.target_shape(args.batch)
 
@@ -208,7 +237,7 @@ def _run_tiled(args) -> int:
         rng = np.random.default_rng([args.seed, step])
         x = rng.standard_normal((args.batch, args.input_hw, args.input_hw, 3), np.float32)
         t = 0.05 * rng.standard_normal(tgt, np.float32)
-        return {"x": jnp.asarray(x), "t": jnp.asarray(t)}
+        return live["arch"].place_batch({"x": x, "t": t})
 
     # Elastic replan: a ClusterChange (fault schedule or a real device
     # monitor) rebuilds the plan for the surviving device set and hands the
@@ -221,7 +250,7 @@ def _run_tiled(args) -> int:
     from repro.models.yolo import l2_loss_local
     from repro.runtime.faults import FaultInjector
 
-    live = {"cluster": cluster, "plan": arch.plan}
+    live = {"cluster": cluster, "plan": arch.plan, "arch": arch}
 
     def replan(ev):
         cl = live["cluster"]
@@ -237,7 +266,7 @@ def _run_tiled(args) -> int:
             loss_local=l2_loss_local,
         )
         _, new_step = make_train_step(new_arch, pcfg, tcfg)
-        live.update(cluster=cl, plan=new_plan)
+        live.update(cluster=cl, plan=new_plan, arch=new_arch)
         print(
             f"replan ({ev.kind}:{ev.device}): grid={new_plan.n}x{new_plan.m} "
             f"rows={new_plan.partition.row_bounds} "
@@ -248,16 +277,12 @@ def _run_tiled(args) -> int:
         )
         return jax.jit(new_step, donate_argnums=(0,)), plan_manifest(new_plan, cl)
 
-    dcfg = DriverConfig(
-        ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
-        log_every=args.log_every, resume=args.resume,
-    )
     report = run_training(
         init_state=init_state,
         train_step=step_fn,
         make_batch=make_batch,
         steps=args.steps,
-        cfg=dcfg,
+        cfg=_driver_config(args),
         seed=args.seed,
         faults=FaultInjector(args.fault_schedule) if args.fault_schedule else None,
         replan=replan,
@@ -269,16 +294,22 @@ def _run_tiled(args) -> int:
         f"replans={report.replans} stragglers={report.straggler_steps} "
         f"loss={m.get('loss', float('nan')):.4f} gnorm={m.get('grad_norm', 0):.3f}"
     )
-    return 0
+    return TiledRun(arch, init_state, step_fn, make_batch, report)
 
 
-def main() -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     _add_args(ap)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
 
     if args.arch == TILED_ARCH:
-        return _run_tiled(args)
+        run_tiled(args)
+        return 0
 
     arch = get_arch(args.arch, reduced=not args.full)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
@@ -304,16 +335,12 @@ def main() -> int:
 
         from repro.runtime.faults import FaultInjector
 
-        dcfg = DriverConfig(
-            ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
-            log_every=args.log_every, resume=args.resume,
-        )
         report = run_training(
             init_state=init_state,
             train_step=step_fn,
             make_batch=make_batch,
             steps=args.steps,
-            cfg=dcfg,
+            cfg=_driver_config(args),
             seed=args.seed,
             faults=(
                 FaultInjector(args.fault_schedule) if args.fault_schedule else None

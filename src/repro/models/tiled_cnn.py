@@ -13,7 +13,10 @@ batch end produces the final gradients the trainer tail consumes.
 
 Batches are dicts ``{"x": (B, H, W, C), "t": (B, OH, OW, Cout)}`` with the
 global batch B divisible by ``grad_accum`` - the same splitting convention
-as the LM path.
+as the LM path.  ``place_batch`` puts them on the tile mesh in the layout
+the executor binds (tiles of x on their devices), and ``state_sharding``
+replicates the parameters and optimizer state over the mesh, so a step's
+inputs start out spread over every device of the grid.
 
 Hybrid plans (``plan.crossover`` set, DESIGN.md §7) need no trainer-side
 changes: batch AND target still enter spatially sharded, the executor
@@ -30,8 +33,9 @@ import dataclasses
 from typing import Callable, Optional
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.fusion import StackPlan
+from repro.core.fusion import StackPlan, _out_spec
 from repro.core.spatial import freeze_bn_stats, init_stack_params
 
 LossLocal = Callable[[jax.Array, jax.Array], tuple[jax.Array, jax.Array]]
@@ -70,6 +74,30 @@ class TiledCNNArch:
         global arrays; the loss/step wrappers and shard-boundary pack do
         the layout transforms."""
         return self.plan.partition
+
+    def state_sharding(self) -> NamedSharding:
+        """Params and optimizer state: replicated over the tile mesh (every
+        device holds a full filter copy, paper §4)."""
+        return NamedSharding(self.mesh, P())
+
+    def batch_shardings(self) -> dict:
+        """Per-key shardings of a ``{"x", "t"}`` batch on the tile mesh,
+        matching the executor's in-specs: spatial tiles for uniform plans,
+        whole maps where the executor packs ragged tiles itself, and the
+        data-side layout for a hybrid plan's target."""
+        plan, ax, bx = self.plan, (self.row_axis, self.col_axis), self.batch_axis
+        x = P(bx, *ax, None) if plan.is_uniform else P(bx, None, None, None)
+        if plan.stages:
+            t = P()
+        elif plan.is_uniform or plan.crossover is not None:
+            t = _out_spec(plan, *ax, bx)
+        else:
+            t = x
+        return {k: NamedSharding(self.mesh, v) for k, v in (("x", x), ("t", t))}
+
+    def place_batch(self, batch: dict) -> dict:
+        shardings = self.batch_shardings()
+        return {k: jax.device_put(v, shardings[k]) for k, v in batch.items()}
 
     def target_shape(self, batch: int) -> tuple[int, ...]:
         return (batch, *self.plan.out_hw(), self.out_channels)

@@ -120,9 +120,7 @@ def sharding_ctx(mesh: Optional[Mesh], rules: Optional[dict[str, Axis]] = None):
     _ACTIVE.rules = {**DEFAULT_RULES, **(rules or {})}
     try:
         if mesh is not None:
-            # jax.set_mesh is newer-jax; `with mesh:` is the portable spelling
-            ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-            with ctx:
+            with jax.set_mesh(mesh):
                 yield
         else:
             yield

@@ -67,8 +67,11 @@ class DriverReport:
     replans: int = 0
     straggler_steps: int = 0
     resumed_step: Optional[int] = None   # checkpoint step resumed from
+    hung: bool = False                   # the watchdog saw no step for hang_timeout
     step_times: list = dataclasses.field(default_factory=list)
+    losses: list = dataclasses.field(default_factory=list)   # per completed step
     last_metrics: Optional[dict] = None
+    final_state: Any = None
 
 
 @dataclasses.dataclass
@@ -242,6 +245,7 @@ def run_training(
                 watchdog.beat()
                 report.step_times.append(dt)
                 report.last_metrics = jax.tree.map(float, metrics)
+                report.losses.append(report.last_metrics["loss"])
                 if cfg.log_every and (step + 1) % cfg.log_every == 0:
                     log.info(
                         "step %d: %s (%.3fs)",
@@ -311,4 +315,6 @@ def run_training(
         mgr.wait()
     finally:
         watchdog.stop()
+        report.hung = watchdog.hung
+    report.final_state = state
     return report

@@ -372,6 +372,22 @@ def test_conv_backends_mixed_precision_grads():
         )
 
 
+def test_pallas_interprets_on_cpu_backend_only(monkeypatch):
+    """Interpret mode belongs to the CPU backend alone: on any other backend
+    the pallas conv is lowered for real - it compiles or raises, never runs
+    slowly in the interpreter (here, off an accelerator, it raises)."""
+    from repro.core import backend as backend_mod
+
+    be = backend_mod.get_conv_backend("pallas")
+    x, w = jnp.ones((1, 6, 6, 4)), jnp.ones((3, 3, 4, 8))
+    assert backend_mod.pallas_interpret()
+    assert be(x, w, None, stride=1, act="linear").shape == (1, 4, 4, 8)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert not backend_mod.pallas_interpret()
+    with pytest.raises(ValueError, match="interpret"):
+        be(x, w, None, stride=1, act="linear")
+
+
 def test_conv2d_tile_mixed_precision_kernel():
     """Kernel-level bf16 x fp32 case vs the (promoting) reference."""
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 14, 14, 8), jnp.bfloat16)

@@ -10,6 +10,7 @@ overhead cost term, and ``schedule="auto"`` resolution.
 import dataclasses
 import itertools
 
+import jax
 import pytest
 
 from repro import compat
@@ -211,3 +212,21 @@ def test_overlap_compat_helpers():
     assert "--xla_gpu_enable_async_collectives=true" not in added2
     assert len(added2) == len(compat.XLA_GPU_OVERLAP_FLAGS) - 1
     assert "=false" in env2["XLA_FLAGS"]
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache goes to one fixed, git-ignored directory of the checkout."""
+    root = compat.COMPILE_CACHE_DIR.parent
+    assert (root / "pyproject.toml").exists()
+    assert f"{compat.COMPILE_CACHE_DIR.name}/" in (root / ".gitignore").read_text().split()
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compat.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert compat.enable_compile_cache() == str(compat.COMPILE_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(compat.COMPILE_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
