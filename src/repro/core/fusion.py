@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import obs
 from repro.core.tiling import (
     Group,
     TilePartition,
@@ -845,43 +846,45 @@ def _apply_group_ragged(
     geom = _ragged_group_geom(plan, gi)
     i = jax.lax.axis_index(row_axis)
     j = jax.lax.axis_index(col_axis)
-    x = halo_exchange_2d_ragged(
-        x,
-        plan.group_halos[gi],
-        row_axis,
-        col_axis,
-        plan.tile_rows[g.start],
-        plan.tile_cols[g.start],
-        dims=(1, 2),
-        out_extents=geom["ein"][0],
-        wire=wire,
-    )
+    with obs.layer_scope(g.start), jax.named_scope(obs.HALO):
+        x = halo_exchange_2d_ragged(
+            x,
+            plan.group_halos[gi],
+            row_axis,
+            col_axis,
+            plan.tile_rows[g.start],
+            plan.tile_cols[g.start],
+            dims=(1, 2),
+            out_extents=geom["ein"][0],
+            wire=wire,
+        )
     for k, l in enumerate(g.layers):
         out_rows = plan.tile_rows[l + 1]
         out_cols = plan.tile_cols[l + 1]
-        x = apply_layer_local_ragged(
-            x,
-            params[l],
-            plan.layers[l],
-            out_halo=geom["halos"][k + 1],
-            out_size=(
-                jnp.asarray(out_rows, jnp.int32)[i],
-                jnp.asarray(out_cols, jnp.int32)[j],
-            ),
-            out_off=(
-                jnp.asarray(_offsets(out_rows), jnp.int32)[i],
-                jnp.asarray(_offsets(out_cols), jnp.int32)[j],
-            ),
-            canon_out_hw=geom["eout"][k],
-            map_out_hw=plan.map_hw[l + 1],
-            row_axis=row_axis,
-            col_axis=col_axis,
-            batch_global=batch_global,
-            batch_axis=batch_axis,
-            backend=plan.backend,
-            block_oh=plan.block_oh,
-            inference=plan.inference,
-        )
+        with obs.layer_scope(l):
+            x = apply_layer_local_ragged(
+                x,
+                params[l],
+                plan.layers[l],
+                out_halo=geom["halos"][k + 1],
+                out_size=(
+                    jnp.asarray(out_rows, jnp.int32)[i],
+                    jnp.asarray(out_cols, jnp.int32)[j],
+                ),
+                out_off=(
+                    jnp.asarray(_offsets(out_rows), jnp.int32)[i],
+                    jnp.asarray(_offsets(out_cols), jnp.int32)[j],
+                ),
+                canon_out_hw=geom["eout"][k],
+                map_out_hw=plan.map_hw[l + 1],
+                row_axis=row_axis,
+                col_axis=col_axis,
+                batch_global=batch_global,
+                batch_axis=batch_axis,
+                backend=plan.backend,
+                block_oh=plan.block_oh,
+                inference=plan.inference,
+            )
     return x
 
 
@@ -916,17 +919,18 @@ def _apply_group_spec(
     geom = _ragged_group_geom(plan, gi)
     i = lax.axis_index(row_axis)
     j = lax.axis_index(col_axis)
-    x = halo_exchange_2d_spec(
-        x,
-        plan.group_halos[gi],
-        row_axis,
-        col_axis,
-        plan.tile_rows[g.start],
-        plan.tile_cols[g.start],
-        dims=(1, 2),
-        out_extents=geom["ein"][0],
-        wire=wire,
-    )
+    with obs.layer_scope(g.start), jax.named_scope(obs.HALO):
+        x = halo_exchange_2d_spec(
+            x,
+            plan.group_halos[gi],
+            row_axis,
+            col_axis,
+            plan.tile_rows[g.start],
+            plan.tile_cols[g.start],
+            dims=(1, 2),
+            out_extents=geom["ein"][0],
+            wire=wire,
+        )
     rtab, runiq = dedup_axis_shapes(plan.tile_rows[g.start])
     ctab, cuniq = dedup_axis_shapes(plan.tile_cols[g.start])
     branch = static_table_lookup(rtab, i) * len(cuniq) + static_table_lookup(ctab, j)
@@ -955,25 +959,26 @@ def _apply_group_spec(
             if mask
             else None
         )
-        x = apply_layer_local_spec(
-            x,
-            params[l],
-            plan.layers[l],
-            branch=branch,
-            branch_io=branch_io,
-            out_halo=geom["halos"][k + 1],
-            canon_out_hw=geom["eout"][k],
-            map_out_hw=plan.map_hw[l + 1],
-            out_off=out_off,
-            row_axis=row_axis,
-            col_axis=col_axis,
-            batch_global=batch_global,
-            batch_axis=batch_axis,
-            mask_offmap=mask,
-            backend=plan.backend,
-            block_oh=plan.block_oh,
-            inference=plan.inference,
-        )
+        with obs.layer_scope(l):
+            x = apply_layer_local_spec(
+                x,
+                params[l],
+                plan.layers[l],
+                branch=branch,
+                branch_io=branch_io,
+                out_halo=geom["halos"][k + 1],
+                canon_out_hw=geom["eout"][k],
+                map_out_hw=plan.map_hw[l + 1],
+                out_off=out_off,
+                row_axis=row_axis,
+                col_axis=col_axis,
+                batch_global=batch_global,
+                batch_axis=batch_axis,
+                mask_offmap=mask,
+                backend=plan.backend,
+                block_oh=plan.block_oh,
+                inference=plan.inference,
+            )
     return x
 
 
@@ -1027,28 +1032,30 @@ def apply_stack_local(
     for gi, g in enumerate(plan.groups):
         if g.mode == "data":
             if gi == 0 or plan.groups[gi - 1].mode != "data":
-                if uniform:
-                    x = reshard_spatial_to_data(x, row_axis, col_axis, wire=wire)
-                else:
-                    x = reshard_spatial_to_data_ragged(
-                        x, row_axis, col_axis,
-                        plan.tile_rows[g.start], plan.tile_cols[g.start],
-                        wire=wire,
-                    )
+                with obs.layer_scope(g.start), jax.named_scope(obs.RESHARD):
+                    if uniform:
+                        x = reshard_spatial_to_data(x, row_axis, col_axis, wire=wire)
+                    else:
+                        x = reshard_spatial_to_data_ragged(
+                            x, row_axis, col_axis,
+                            plan.tile_rows[g.start], plan.tile_cols[g.start],
+                            wire=wire,
+                        )
             for l in g.layers:
-                x = apply_layer_data(
-                    x,
-                    params[l],
-                    plan.layers[l],
-                    map_out_hw=plan.map_hw[l + 1],
-                    row_axis=row_axis,
-                    col_axis=col_axis,
-                    batch_global=bg,
-                    backend=plan.backend,
-                    batch_axis=batch_axis,
-                    block_oh=plan.block_oh,
-                    inference=plan.inference,
-                )
+                with obs.layer_scope(l):
+                    x = apply_layer_data(
+                        x,
+                        params[l],
+                        plan.layers[l],
+                        map_out_hw=plan.map_hw[l + 1],
+                        row_axis=row_axis,
+                        col_axis=col_axis,
+                        batch_global=bg,
+                        backend=plan.backend,
+                        batch_axis=batch_axis,
+                        block_oh=plan.block_oh,
+                        inference=plan.inference,
+                    )
             continue
         if not uniform:
             group_fn = (
@@ -1064,45 +1071,48 @@ def apply_stack_local(
         layers = list(g.layers)
         if plan.schedule == "overlap" and any(plan.group_halos[gi]):
             lead = layers.pop(0)
-            x = apply_group_lead_overlap(
-                x,
-                params[lead],
-                plan.layers[lead],
-                halo=plan.group_halos[gi],
-                out_halo=plan.rem_halos[lead],
-                shard_out_hw=plan.shard_hw[lead + 1],
-                map_out_hw=plan.map_hw[lead + 1],
-                row_axis=row_axis,
-                col_axis=col_axis,
-                batch_global=bg,
-                mask_offmap=(lead != g.end),
-                backend=plan.backend,
-                batch_axis=batch_axis,
-                block_oh=plan.block_oh,
-                wire=wire,
-                inference=plan.inference,
-            )
+            with obs.layer_scope(lead):
+                x = apply_group_lead_overlap(
+                    x,
+                    params[lead],
+                    plan.layers[lead],
+                    halo=plan.group_halos[gi],
+                    out_halo=plan.rem_halos[lead],
+                    shard_out_hw=plan.shard_hw[lead + 1],
+                    map_out_hw=plan.map_hw[lead + 1],
+                    row_axis=row_axis,
+                    col_axis=col_axis,
+                    batch_global=bg,
+                    mask_offmap=(lead != g.end),
+                    backend=plan.backend,
+                    batch_axis=batch_axis,
+                    block_oh=plan.block_oh,
+                    wire=wire,
+                    inference=plan.inference,
+                )
         else:
-            x = halo_exchange_2d(
-                x, plan.group_halos[gi], row_axis, col_axis, dims=(1, 2), wire=wire
-            )
+            with obs.layer_scope(g.start), jax.named_scope(obs.HALO):
+                x = halo_exchange_2d(
+                    x, plan.group_halos[gi], row_axis, col_axis, dims=(1, 2), wire=wire
+                )
         for l in layers:
-            x = apply_layer_local(
-                x,
-                params[l],
-                plan.layers[l],
-                out_halo=plan.rem_halos[l],
-                shard_out_hw=plan.shard_hw[l + 1],
-                map_out_hw=plan.map_hw[l + 1],
-                row_axis=row_axis,
-                col_axis=col_axis,
-                batch_global=bg,
-                mask_offmap=(l != g.end),
-                backend=plan.backend,
-                batch_axis=batch_axis,
-                block_oh=plan.block_oh,
-                inference=plan.inference,
-            )
+            with obs.layer_scope(l):
+                x = apply_layer_local(
+                    x,
+                    params[l],
+                    plan.layers[l],
+                    out_halo=plan.rem_halos[l],
+                    shard_out_hw=plan.shard_hw[l + 1],
+                    map_out_hw=plan.map_hw[l + 1],
+                    row_axis=row_axis,
+                    col_axis=col_axis,
+                    batch_global=bg,
+                    mask_offmap=(l != g.end),
+                    backend=plan.backend,
+                    batch_axis=batch_axis,
+                    block_oh=plan.block_oh,
+                    inference=plan.inference,
+                )
     return x
 
 
@@ -1295,25 +1305,27 @@ def _apply_spatial_prefix(
     for gi, g in enumerate(plan.groups):
         if g.mode != "spatial":
             break
-        x = halo_exchange_2d(
-            x, plan.group_halos[gi], row_axis, col_axis, dims=(1, 2), wire=wire
-        )
-        for l in g.layers:
-            x = apply_layer_local(
-                x,
-                params[l],
-                plan.layers[l],
-                out_halo=plan.rem_halos[l],
-                shard_out_hw=plan.shard_hw[l + 1],
-                map_out_hw=plan.map_hw[l + 1],
-                row_axis=row_axis,
-                col_axis=col_axis,
-                batch_global=bg,
-                mask_offmap=(l != g.end),
-                backend=plan.backend,
-                batch_axis=None,
-                block_oh=plan.block_oh,
+        with obs.layer_scope(g.start), jax.named_scope(obs.HALO):
+            x = halo_exchange_2d(
+                x, plan.group_halos[gi], row_axis, col_axis, dims=(1, 2), wire=wire
             )
+        for l in g.layers:
+            with obs.layer_scope(l):
+                x = apply_layer_local(
+                    x,
+                    params[l],
+                    plan.layers[l],
+                    out_halo=plan.rem_halos[l],
+                    shard_out_hw=plan.shard_hw[l + 1],
+                    map_out_hw=plan.map_hw[l + 1],
+                    row_axis=row_axis,
+                    col_axis=col_axis,
+                    batch_global=bg,
+                    mask_offmap=(l != g.end),
+                    backend=plan.backend,
+                    batch_axis=None,
+                    block_oh=plan.block_oh,
+                )
     return x
 
 
@@ -1395,18 +1407,19 @@ def _make_pipeline_local(
         def f(params, xc):
             x = xc[:, :hin, :win, :cin]
             for l in g.layers:
-                x = apply_layer_data(
-                    x,
-                    params[l],
-                    plan.layers[l],
-                    map_out_hw=plan.map_hw[l + 1],
-                    row_axis=row_axis,
-                    col_axis=col_axis,
-                    batch_global=bg,
-                    backend=plan.backend,
-                    batch_axis=None,
-                    block_oh=plan.block_oh,
-                )
+                with obs.layer_scope(l):
+                    x = apply_layer_data(
+                        x,
+                        params[l],
+                        plan.layers[l],
+                        map_out_hw=plan.map_hw[l + 1],
+                        row_axis=row_axis,
+                        col_axis=col_axis,
+                        batch_global=bg,
+                        backend=plan.backend,
+                        batch_axis=None,
+                        block_oh=plan.block_oh,
+                    )
             return _to_container(x)
 
         return f
@@ -1428,18 +1441,20 @@ def _make_pipeline_local(
                 params, x_mu, plan, row_axis=row_axis, col_axis=col_axis, bg=bg,
                 wire=wire,
             )
-            h = lax.all_gather(h, row_axis, axis=1, tiled=True)
-            h = lax.all_gather(h, col_axis, axis=2, tiled=True)
-            entry = lax.dynamic_slice_in_dim(h, rank * bp, bp, axis=0)
+            with obs.layer_scope(geom["pfirst"]), jax.named_scope(obs.RESHARD):
+                h = lax.all_gather(h, row_axis, axis=1, tiled=True)
+                h = lax.all_gather(h, col_axis, axis=2, tiled=True)
+                entry = lax.dynamic_slice_in_dim(h, rank * bp, bp, axis=0)
             x_in = jnp.where(jnp.equal(stage, 0), _to_container(entry), buf)
             out = lax.switch(stage, branches, params, x_in)
             k_l = jnp.clip(t - (n_st - 1), 0, mb - 1)
             t_mu = lax.dynamic_index_in_dim(ts, k_l, axis=0, keepdims=False)
             t_blk = lax.dynamic_slice_in_dim(t_mu, rank * bp, bp, axis=0)
             y = out[:, :h_out, :w_out, :c_out]
-            s_l, c_l = loss_local(y, t_blk)
-            s_l = jnp.asarray(s_l, jnp.float32)
-            c_l = jnp.asarray(c_l, jnp.float32)
+            with jax.named_scope(obs.LOSS):
+                s_l, c_l = loss_local(y, t_blk)
+                s_l = jnp.asarray(s_l, jnp.float32)
+                c_l = jnp.asarray(c_l, jnp.float32)
             valid = jnp.logical_and(jnp.equal(stage, n_st - 1), t >= n_st - 1)
             s_acc = s_acc + jnp.where(valid, s_l, 0.0)
             c_acc = c_acc + jnp.where(valid, c_l, 0.0)
@@ -1666,7 +1681,8 @@ def make_tiled_loss(
 
         def pfn(params, xs, ts):
             s, c = local(params, xs, ts)
-            return lax.psum(s, axes) / lax.psum(c, axes)
+            with jax.named_scope(obs.LOSS):
+                return lax.psum(s, axes) / lax.psum(c, axes)
 
         mapped = jax.shard_map(
             pfn,
@@ -1709,17 +1725,18 @@ def make_tiled_loss(
             batch_axis=batch_axis, batch_global=batch_global,
             wire=wire,
         )
-        if spec_exec and plan.crossover is None:
-            s, c = _spec_core_loss(y, target, plan, loss_local, row_axis, col_axis)
-        else:
-            s, c = loss_local(y, target)
-            if ragged_out:
-                # pad slots hold y = t = 0 (executor mask / packed target), so
-                # the sum is exact; rescale the count to valid elements only.
-                c = c * _ragged_count_scale(plan, row_axis, col_axis)
-        s = lax.psum(s, axes)
-        c = lax.psum(c, axes)
-        return s / c
+        with jax.named_scope(obs.LOSS):
+            if spec_exec and plan.crossover is None:
+                s, c = _spec_core_loss(y, target, plan, loss_local, row_axis, col_axis)
+            else:
+                s, c = loss_local(y, target)
+                if ragged_out:
+                    # pad slots hold y = t = 0 (executor mask / packed target), so
+                    # the sum is exact; rescale the count to valid elements only.
+                    c = c * _ragged_count_scale(plan, row_axis, col_axis)
+            s = lax.psum(s, axes)
+            c = lax.psum(c, axes)
+            return s / c
 
     mapped = jax.shard_map(
         fn,
@@ -1793,9 +1810,10 @@ def make_deferred_grad_step(
             )
             # The single end-of-batch aggregation, shared with the
             # non-pipeline path (partial sums -> final grads).
-            cnt_g = lax.psum(c_tot, pipe_axes)
-            grads = jax.tree.map(lambda a: lax.psum(a, pipe_axes) / cnt_g, g)
-            loss = lax.psum(s_tot, pipe_axes) / cnt_g
+            with jax.named_scope(obs.GRAD_SUM):
+                cnt_g = lax.psum(c_tot, pipe_axes)
+                grads = jax.tree.map(lambda a: lax.psum(a, pipe_axes) / cnt_g, g)
+                loss = lax.psum(s_tot, pipe_axes) / cnt_g
             return loss, grads
 
         pmapped = jax.shard_map(
@@ -1844,12 +1862,13 @@ def make_deferred_grad_step(
             batch_axis=batch_axis, batch_global=batch_global,
             wire=wire,
         )
-        if spec_exec and plan.crossover is None:
-            s, c = _spec_core_loss(y, t, plan, loss_local, row_axis, col_axis)
-        else:
-            s, c = loss_local(y, t)
-            if ragged_out:
-                c = c * _ragged_count_scale(plan, row_axis, col_axis)
+        with jax.named_scope(obs.LOSS):
+            if spec_exec and plan.crossover is None:
+                s, c = _spec_core_loss(y, t, plan, loss_local, row_axis, col_axis)
+            else:
+                s, c = loss_local(y, t)
+                if ragged_out:
+                    c = c * _ragged_count_scale(plan, row_axis, col_axis)
         # Divide by the *global* count; the cross-tile sum is deferred to the
         # gradient aggregation (linearity), matching the paper's schedule.
         return s, c
@@ -1865,15 +1884,17 @@ def make_deferred_grad_step(
                 def _upd(a, b):
                     return a + b
 
-                acc = jax.tree.map(_upd, acc, g)
+                with jax.named_scope(obs.GRAD_SUM):
+                    acc = jax.tree.map(_upd, acc, g)
                 return (acc, loss_acc + s, cnt_acc + c), None
 
             zeros = jax.tree.map(jnp.zeros_like, params)
             (acc, loss_sum, cnt), _ = lax.scan(step, (zeros, 0.0, 0.0), (xs, ts))
             # The single end-of-batch aggregation (partial sums -> final grads).
-            cnt_g = lax.psum(cnt, tile_axes)
-            grads = jax.tree.map(lambda a: lax.psum(a, tile_axes) / cnt_g, acc)
-            loss = lax.psum(loss_sum, tile_axes) / cnt_g
+            with jax.named_scope(obs.GRAD_SUM):
+                cnt_g = lax.psum(cnt, tile_axes)
+                grads = jax.tree.map(lambda a: lax.psum(a, tile_axes) / cnt_g, acc)
+                loss = lax.psum(loss_sum, tile_axes) / cnt_g
             return loss, grads
 
     else:
@@ -1910,16 +1931,18 @@ def make_deferred_grad_step(
                 (s, c), (g, new_ef) = jax.value_and_grad(
                     local_loss_ef, argnums=(0, 1), has_aux=True
                 )(params, ef, x, t)
-                acc = jax.tree.map(lambda a, b: a + b, acc, g)
+                with jax.named_scope(obs.GRAD_SUM):
+                    acc = jax.tree.map(lambda a, b: a + b, acc, g)
                 return (acc, new_ef, loss_acc + s, cnt_acc + c), None
 
             zeros = jax.tree.map(jnp.zeros_like, params)
             (acc, _, loss_sum, cnt), _ = lax.scan(
                 step, (zeros, ef0, 0.0, 0.0), (xs, ts)
             )
-            cnt_g = lax.psum(cnt, tile_axes)
-            grads = jax.tree.map(lambda a: lax.psum(a, tile_axes) / cnt_g, acc)
-            loss = lax.psum(loss_sum, tile_axes) / cnt_g
+            with jax.named_scope(obs.GRAD_SUM):
+                cnt_g = lax.psum(cnt, tile_axes)
+                grads = jax.tree.map(lambda a: lax.psum(a, tile_axes) / cnt_g, acc)
+                loss = lax.psum(loss_sum, tile_axes) / cnt_g
             return loss, grads
 
     mapped = jax.shard_map(

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core.tiling import ConvSpec
 from repro.core.halo import (
     WireCtx,
@@ -474,16 +475,23 @@ def _conv_or_pool(
 
     Returns ``(y, fused)`` where ``fused`` says the activation was applied by
     the backend.  The decision depends only on (layer, backend), so splitting
-    a tile into slabs and applying this per slab is exact.
+    a tile into slabs and applying this per slab is exact.  Runs under the
+    named scope ``pool`` or ``conv`` (``_compute_scope``).
     """
-    if layer.pool:
-        return _valid_pool(x, layer.kernel, layer.stride), False
-    be = get_conv_backend(backend)
-    fused = (not layer.batch_norm) and layer.act in be.fused_acts
-    b = params["b"] if layer.use_bias else None
-    y = be(x, params["w"], b, stride=layer.stride,
-           act=layer.act if fused else "linear", block_oh=block_oh)
-    return y, fused
+    with _compute_scope(layer):
+        if layer.pool:
+            return _valid_pool(x, layer.kernel, layer.stride), False
+        be = get_conv_backend(backend)
+        fused = (not layer.batch_norm) and layer.act in be.fused_acts
+        b = params["b"] if layer.use_bias else None
+        y = be(x, params["w"], b, stride=layer.stride,
+               act=layer.act if fused else "linear", block_oh=block_oh)
+        return y, fused
+
+
+def _compute_scope(layer: LayerDef):
+    """The named scope of a layer's conv or pool compute (``repro.obs``)."""
+    return jax.named_scope(obs.POOL if layer.pool else obs.CONV)
 
 
 def _conv_or_pool_spec(
@@ -503,16 +511,18 @@ def _conv_or_pool_spec(
     """
     if layer.pool:
         if layer.kernel == layer.stride:
-            return _pool_nonoverlap(x, layer.kernel), False
+            with _compute_scope(layer):
+                return _pool_nonoverlap(x, layer.kernel), False
         return _conv_or_pool(x, params, layer, backend, block_oh)
     if backend != "xla" or layer.stride != 1:
         return _conv_or_pool(x, params, layer, backend, block_oh)
     fused = (not layer.batch_norm) and layer.act in get_conv_backend(backend).fused_acts
-    y = _conv_valid_s1(x, params["w"])
-    if layer.use_bias:
-        y = y + params["b"]
-    if fused:
-        y = _ACTIVATIONS[layer.act](y)
+    with _compute_scope(layer):
+        y = _conv_valid_s1(x, params["w"])
+        if layer.use_bias:
+            y = y + params["b"]
+        if fused:
+            y = _ACTIVATIONS[layer.act](y)
     return y, fused
 
 
@@ -532,26 +542,27 @@ def _finish_layer(
     batch_axis: str | None,
     inference: bool = False,
 ) -> jax.Array:
-    """Post-conv tail shared by the sync and overlap executors: cross-tile
-    BN (frozen-stats BN for inference plans - no psum), unfused activation,
-    off-map masking."""
-    if layer.batch_norm and not layer.pool:
-        if inference:
-            y = _bn_infer(y, params, layer)
-        else:
-            n_global = batch_global * map_out_hw[0] * map_out_hw[1]
-            bn_axes = (row_axis, col_axis)
-            if batch_axis is not None:
-                bn_axes = (batch_axis,) + bn_axes
-            y = _bn_tiled(y, layer, params, out_halo, bn_axes, n_global)
-    if not fused:
-        y = _ACTIVATIONS[layer.act](y)
-    if mask_offmap and any(h > 0 for h in out_halo):
-        m = _offmap_mask(
-            y.shape[1], y.shape[2], out_halo, shard_out_hw, map_out_hw, row_axis, col_axis
-        )
-        y = y * m[None, :, :, None].astype(y.dtype)
-    return y
+    """Post-conv tail shared by the sync and overlap executors, under the
+    named scope ``bn``: cross-tile BN (frozen-stats BN for inference plans -
+    no psum), unfused activation, off-map masking."""
+    with jax.named_scope(obs.BN):
+        if layer.batch_norm and not layer.pool:
+            if inference:
+                y = _bn_infer(y, params, layer)
+            else:
+                n_global = batch_global * map_out_hw[0] * map_out_hw[1]
+                bn_axes = (row_axis, col_axis)
+                if batch_axis is not None:
+                    bn_axes = (batch_axis,) + bn_axes
+                y = _bn_tiled(y, layer, params, out_halo, bn_axes, n_global)
+        if not fused:
+            y = _ACTIVATIONS[layer.act](y)
+        if mask_offmap and any(h > 0 for h in out_halo):
+            m = _offmap_mask(
+                y.shape[1], y.shape[2], out_halo, shard_out_hw, map_out_hw, row_axis, col_axis
+            )
+            y = y * m[None, :, :, None].astype(y.dtype)
+        return y
 
 
 # ---------------------------------------------------------------------------
@@ -651,23 +662,24 @@ def apply_layer_local_ragged(
     the ragged core only, and the combined validity/off-map mask re-zeroes
     pad slots so the invariant holds for the next layer."""
     y, fused = _conv_or_pool(x, params, layer, backend, block_oh)
-    y = _fit_extent(y, canon_out_hw)
-    if layer.batch_norm and not layer.pool:
-        if inference:
-            # frozen stats: elementwise, pad slots re-zeroed by the mask below
-            y = _bn_infer(y, params, layer)
-        else:
-            n_global = batch_global * map_out_hw[0] * map_out_hw[1]
-            bn_axes = (row_axis, col_axis)
-            if batch_axis is not None:
-                bn_axes = (batch_axis,) + bn_axes
-            mask = _core_mask_ragged(y.shape[1], y.shape[2], out_halo, out_size)
-            mean, var = _masked_batch_stats(y, mask[None, :, :, None], bn_axes, n_global)
-            y = _bn_apply(y, mean, var, params["bn_scale"], params["bn_bias"])
-    if not fused:
-        y = _ACTIVATIONS[layer.act](y)
-    m = _ragged_mask(y.shape[1], y.shape[2], out_halo, out_size, out_off, map_out_hw)
-    return y * m[None, :, :, None].astype(y.dtype)
+    with jax.named_scope(obs.BN):
+        y = _fit_extent(y, canon_out_hw)
+        if layer.batch_norm and not layer.pool:
+            if inference:
+                # frozen stats: elementwise, pad slots re-zeroed by the mask below
+                y = _bn_infer(y, params, layer)
+            else:
+                n_global = batch_global * map_out_hw[0] * map_out_hw[1]
+                bn_axes = (row_axis, col_axis)
+                if batch_axis is not None:
+                    bn_axes = (batch_axis,) + bn_axes
+                mask = _core_mask_ragged(y.shape[1], y.shape[2], out_halo, out_size)
+                mean, var = _masked_batch_stats(y, mask[None, :, :, None], bn_axes, n_global)
+                y = _bn_apply(y, mean, var, params["bn_scale"], params["bn_bias"])
+        if not fused:
+            y = _ACTIVATIONS[layer.act](y)
+        m = _ragged_mask(y.shape[1], y.shape[2], out_halo, out_size, out_off, map_out_hw)
+        return y * m[None, :, :, None].astype(y.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -758,17 +770,18 @@ def apply_layer_local_spec(
                     f"spec branch geometry drift: conv of {(vin_r, vin_c)} "
                     f"gave {y.shape[1:3]}, planner said {(vout_r, vout_c)}"
                 )
-            outs = []
-            if bn_stats:
-                outs = [jnp.sum(_spec_core(y, vout_r, vout_c), axis=(0, 1, 2))]
-            pad = [
-                (0, 0),
-                (0, canon_out_hw[0] - vout_r),
-                (0, canon_out_hw[1] - vout_c),
-                (0, 0),
-            ]
-            y = jnp.pad(y, pad)
-            return (y, *outs) if outs else y
+            with jax.named_scope(obs.BN):
+                outs = []
+                if bn_stats:
+                    outs = [jnp.sum(_spec_core(y, vout_r, vout_c), axis=(0, 1, 2))]
+                pad = [
+                    (0, 0),
+                    (0, canon_out_hw[0] - vout_r),
+                    (0, canon_out_hw[1] - vout_c),
+                    (0, 0),
+                ]
+                y = jnp.pad(y, pad)
+                return (y, *outs) if outs else y
 
         return f
 
@@ -778,36 +791,37 @@ def apply_layer_local_spec(
         fused = False
     else:
         fused = (not layer.batch_norm) and layer.act in get_conv_backend(backend).fused_acts
-    if bn_stats:
-        # centred two-pass statistics, as in _masked_batch_stats; each
-        # branch sums over its own static core window
-        y, s = res
-        n_global = batch_global * map_out_hw[0] * map_out_hw[1]
-        bn_axes = (row_axis, col_axis)
-        if batch_axis is not None:
-            bn_axes = (batch_axis,) + bn_axes
-        mean = lax.psum(s, bn_axes) / n_global
+    with jax.named_scope(obs.BN):
+        if bn_stats:
+            # centred two-pass statistics, as in _masked_batch_stats; each
+            # branch sums over its own static core window
+            y, s = res
+            n_global = batch_global * map_out_hw[0] * map_out_hw[1]
+            bn_axes = (row_axis, col_axis)
+            if batch_axis is not None:
+                bn_axes = (batch_axis,) + bn_axes
+            mean = lax.psum(s, bn_axes) / n_global
 
-        def mk_ss(io):
-            (_, _), (vout_r, vout_c) = io
-            return lambda a: jnp.sum(
-                jnp.square(_spec_core(a, vout_r, vout_c) - mean), axis=(0, 1, 2)
-            )
+            def mk_ss(io):
+                (_, _), (vout_r, vout_c) = io
+                return lambda a: jnp.sum(
+                    jnp.square(_spec_core(a, vout_r, vout_c) - mean), axis=(0, 1, 2)
+                )
 
-        ss = _switch_by_size(branch, [mk_ss(io) for io in branch_io], y)
-        var = lax.psum(ss, bn_axes) / n_global
-        y = _bn_apply(y, mean, var, params["bn_scale"], params["bn_bias"])
-    else:
-        y = res
-        if bn:
-            y = _bn_infer(y, params, layer)
-    if not fused:
-        y = _ACTIVATIONS[layer.act](y)
-    if mask_offmap and any(h > 0 for h in out_halo):
-        assert out_off is not None
-        m = _offmap_mask_spec(y.shape[1], y.shape[2], out_halo, out_off, map_out_hw)
-        y = y * m[None, :, :, None].astype(y.dtype)
-    return y
+            ss = _switch_by_size(branch, [mk_ss(io) for io in branch_io], y)
+            var = lax.psum(ss, bn_axes) / n_global
+            y = _bn_apply(y, mean, var, params["bn_scale"], params["bn_bias"])
+        else:
+            y = res
+            if bn:
+                y = _bn_infer(y, params, layer)
+        if not fused:
+            y = _ACTIVATIONS[layer.act](y)
+        if mask_offmap and any(h > 0 for h in out_halo):
+            assert out_off is not None
+            m = _offmap_mask_spec(y.shape[1], y.shape[2], out_halo, out_off, map_out_hw)
+            y = y * m[None, :, :, None].astype(y.dtype)
+        return y
 
 
 # ---------------------------------------------------------------------------
@@ -981,7 +995,8 @@ def apply_layer_data(
     the tile axes now enumerate *batch shards*, so reducing over the same
     axes with the global ``batch x H x W`` count keeps statistics exact
     (each (sample, position) is owned by exactly one device)."""
-    xp = pad_for_valid(x, layer.padding, pool=layer.pool)
+    with _compute_scope(layer):
+        xp = pad_for_valid(x, layer.padding, pool=layer.pool)
     y, fused = _conv_or_pool(xp, params, layer, backend, block_oh)
     return _finish_layer(
         y,
@@ -1103,46 +1118,56 @@ def apply_group_lead_overlap(
     )
 
     # 1. issue the packed row exchange (nothing below consumes it yet)
-    row_lo, row_hi = halo_exchange_1d_packed(x, top, bottom, row_axis, dim=1, wire=wire)
+    with jax.named_scope(obs.HALO):
+        row_lo, row_hi = halo_exchange_1d_packed(x, top, bottom, row_axis, dim=1, wire=wire)
 
     if rs is None or cs is None:
         # no interior: whole-tile compute on the assembled extended tile
-        ext = _assemble(row_lo, x, row_hi, top, bottom, dim=1)
-        col_lo, col_hi = halo_exchange_1d_packed(
-            ext, left, right, col_axis, dim=2, wire=wire
-        )
-        ext = _assemble(col_lo, ext, col_hi, left, right, dim=2)
+        with jax.named_scope(obs.HALO):
+            ext = _assemble(row_lo, x, row_hi, top, bottom, dim=1)
+            col_lo, col_hi = halo_exchange_1d_packed(
+                ext, left, right, col_axis, dim=2, wire=wire
+            )
+            ext = _assemble(col_lo, ext, col_hi, left, right, dim=2)
         y, fused = _conv_or_pool(ext, params, layer, backend, block_oh)
         return finish(y, fused=fused)
 
+    def cut(a, rows, cols):
+        # the slab a conv or pool reads, under its scope (so is its backward pad)
+        with _compute_scope(layer):
+            return a[:, rows, cols, :]
+
     # 2. interior compute from owned data only - independent of all recvs
-    int_slab = x[:, rs.int_in_lo:rs.int_in_hi, cs.int_in_lo:cs.int_in_hi, :]
+    int_slab = cut(x, slice(rs.int_in_lo, rs.int_in_hi), slice(cs.int_in_lo, cs.int_in_hi))
     y_int, fused = _conv_or_pool(int_slab, params, layer, backend, block_oh)
 
     # 3. column exchange over the row-extended tile (carries the corners)
-    x_rows = _assemble(row_lo, x, row_hi, top, bottom, dim=1)
-    col_lo, col_hi = halo_exchange_1d_packed(
-        x_rows, left, right, col_axis, dim=2, wire=wire
-    )
-    ext = _assemble(col_lo, x_rows, col_hi, left, right, dim=2)
+    with jax.named_scope(obs.HALO):
+        x_rows = _assemble(row_lo, x, row_hi, top, bottom, dim=1)
+        col_lo, col_hi = halo_exchange_1d_packed(
+            x_rows, left, right, col_axis, dim=2, wire=wire
+        )
+        ext = _assemble(col_lo, x_rows, col_hi, left, right, dim=2)
 
     # 4. boundary strips once the halo strips land (extended coords)
     mid_rows = slice(rs.i0 * s, rs.i1 * s + k)
     mid = [y_int]
     if cs.n_lo:
-        slab = ext[:, mid_rows, 0:(cs.i0 - 1) * s + k, :]
+        slab = cut(ext, mid_rows, slice(0, (cs.i0 - 1) * s + k))
         mid.insert(0, _conv_or_pool(slab, params, layer, backend, block_oh)[0])
     if cs.n_hi:
-        slab = ext[:, mid_rows, (cs.i1 + 1) * s:(cs.out - 1) * s + k, :]
+        slab = cut(ext, mid_rows, slice((cs.i1 + 1) * s, (cs.out - 1) * s + k))
         mid.append(_conv_or_pool(slab, params, layer, backend, block_oh)[0])
-    bands = [mid[0] if len(mid) == 1 else jnp.concatenate(mid, axis=2)]
+    with _compute_scope(layer):
+        bands = [mid[0] if len(mid) == 1 else jnp.concatenate(mid, axis=2)]
     if rs.n_lo:
-        slab = ext[:, 0:(rs.i0 - 1) * s + k, :, :]
+        slab = cut(ext, slice(0, (rs.i0 - 1) * s + k), slice(None))
         bands.insert(0, _conv_or_pool(slab, params, layer, backend, block_oh)[0])
     if rs.n_hi:
-        slab = ext[:, (rs.i1 + 1) * s:(rs.out - 1) * s + k, :, :]
+        slab = cut(ext, slice((rs.i1 + 1) * s, (rs.out - 1) * s + k), slice(None))
         bands.append(_conv_or_pool(slab, params, layer, backend, block_oh)[0])
-    y = bands[0] if len(bands) == 1 else jnp.concatenate(bands, axis=1)
+    with _compute_scope(layer):
+        y = bands[0] if len(bands) == 1 else jnp.concatenate(bands, axis=1)
     return finish(y, fused=fused)
 
 

@@ -33,8 +33,10 @@ import dataclasses
 from typing import Callable, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.fusion import StackPlan, _out_spec
 from repro.core.spatial import freeze_bn_stats, init_stack_params
 
@@ -96,8 +98,12 @@ class TiledCNNArch:
         return {k: NamedSharding(self.mesh, v) for k, v in (("x", x), ("t", t))}
 
     def place_batch(self, batch: dict) -> dict:
+        """Put a host batch on the tile mesh, under the span
+        ``arch.place_batch`` with the bytes placed as ``bytes``."""
         shardings = self.batch_shardings()
-        return {k: jax.device_put(v, shardings[k]) for k, v in batch.items()}
+        nbytes = sum(v.nbytes for v in batch.values())
+        with TraceAnnotation(obs.PLACE_BATCH, bytes=nbytes):
+            return {k: jax.device_put(v, shardings[k]) for k, v in batch.items()}
 
     def target_shape(self, batch: int) -> tuple[int, ...]:
         return (batch, *self.plan.out_hw(), self.out_channels)

@@ -39,7 +39,9 @@ from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
+from repro import obs
 from repro.ckpt.manager import CheckpointManager
 from repro.runtime.faults import ClusterChange, FaultInjector
 
@@ -233,39 +235,47 @@ def run_training(
     try:
         while step < steps:
             try:
-                t0 = time.monotonic()
-                if faults is not None:
-                    faults.on_step(step)
-                if fault_hook is not None:
-                    fault_hook(step)
-                batch = make_batch(step)
-                state, metrics = train_step(state, batch)
-                jax.block_until_ready(metrics["loss"])
-                dt = time.monotonic() - t0
-                watchdog.beat()
-                report.step_times.append(dt)
-                report.last_metrics = jax.tree.map(float, metrics)
-                report.losses.append(report.last_metrics["loss"])
-                if cfg.log_every and (step + 1) % cfg.log_every == 0:
-                    log.info(
-                        "step %d: %s (%.3fs)",
-                        step,
-                        " ".join(
-                            f"{k}={v:.5g}" for k, v in sorted(report.last_metrics.items())
-                        ),
-                        dt,
-                    )
-                if len(report.step_times) >= 5:
-                    med = statistics.median(report.step_times[-50:])
-                    if dt > cfg.straggler_factor * med:
-                        report.straggler_steps += 1
-                        log.warning(
-                            "straggler: step %d took %.3fs (median %.3fs)", step, dt, med
-                        )
-                report.steps_done += 1
-                if (step + 1) % cfg.ckpt_every == 0 or step + 1 == steps:
-                    mgr.save(step, state, blocking=not cfg.async_ckpt, plan=plan)
-                step += 1
+                with StepTraceAnnotation(obs.STEP, step_num=step):
+                    t0 = time.monotonic()
+                    if faults is not None:
+                        faults.on_step(step)
+                    if fault_hook is not None:
+                        fault_hook(step)
+                    with TraceAnnotation(obs.MAKE_BATCH):
+                        batch = make_batch(step)
+                    with TraceAnnotation(obs.DISPATCH):
+                        state, metrics = train_step(state, batch)
+                    with TraceAnnotation(obs.WAIT):
+                        jax.block_until_ready(metrics["loss"])
+                    dt = time.monotonic() - t0
+                    watchdog.beat()
+                    with TraceAnnotation(obs.METRICS):
+                        report.step_times.append(dt)
+                        report.last_metrics = jax.tree.map(float, metrics)
+                        report.losses.append(report.last_metrics["loss"])
+                        if cfg.log_every and (step + 1) % cfg.log_every == 0:
+                            log.info(
+                                "step %d: %s (%.3fs)",
+                                step,
+                                " ".join(
+                                    f"{k}={v:.5g}"
+                                    for k, v in sorted(report.last_metrics.items())
+                                ),
+                                dt,
+                            )
+                        if len(report.step_times) >= 5:
+                            med = statistics.median(report.step_times[-50:])
+                            if dt > cfg.straggler_factor * med:
+                                report.straggler_steps += 1
+                                log.warning(
+                                    "straggler: step %d took %.3fs (median %.3fs)",
+                                    step, dt, med,
+                                )
+                    report.steps_done += 1
+                    if (step + 1) % cfg.ckpt_every == 0 or step + 1 == steps:
+                        with TraceAnnotation(obs.CHECKPOINT, step=step):
+                            mgr.save(step, state, blocking=not cfg.async_ckpt, plan=plan)
+                    step += 1
             except ClusterChange as ev:
                 # elastic path: the device set changed - rebuild the plan
                 # for the survivors and keep the live state (its leaves are
@@ -276,8 +286,9 @@ def run_training(
                     mgr.wait()
                     raise
                 log.warning("cluster change: %s; replanning", ev)
-                mgr.wait()            # drain in-flight save before remap
-                train_step, plan = replan(ev)
+                with TraceAnnotation(obs.REPLAN, step=step):
+                    mgr.wait()            # drain in-flight save before remap
+                    train_step, plan = replan(ev)
                 if isinstance(plan, dict) and plan.get("groups"):
                     # surface what the replan decided: per-group partition
                     # modes, and stage device ranges for pipeline plans
@@ -298,20 +309,21 @@ def run_training(
                 if restarts > cfg.max_restarts:
                     mgr.wait()
                     raise
-                try:
-                    mgr.wait()
-                except Exception:  # noqa: BLE001 - async save failure; disk
-                    log.exception("async save failed during restart; "
-                                  "restoring from last committed step")
-                if mgr.latest_step() is not None:
-                    abstract = jax.eval_shape(fresh)
-                    state, loaded = mgr.restored_step(
-                        abstract, shardings=state_shardings
-                    )
-                    step = loaded + 1
-                else:
-                    state = fresh()
-                    step = 0
+                with TraceAnnotation(obs.RESTORE, step=step):
+                    try:
+                        mgr.wait()
+                    except Exception:  # noqa: BLE001 - async save failure; disk
+                        log.exception("async save failed during restart; "
+                                      "restoring from last committed step")
+                    if mgr.latest_step() is not None:
+                        abstract = jax.eval_shape(fresh)
+                        state, loaded = mgr.restored_step(
+                            abstract, shardings=state_shardings
+                        )
+                        step = loaded + 1
+                    else:
+                        state = fresh()
+                        step = 0
         mgr.wait()
     finally:
         watchdog.stop()

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.optim import (
     clip_by_global_norm,
@@ -59,15 +60,17 @@ def _make_init_state(arch, opt, tcfg: TrainConfig):
 def _apply_updates(
     state: TrainState, loss, grads, opt, tcfg: TrainConfig
 ) -> tuple[TrainState, dict]:
-    """Shared trainer tail: EF compression -> clip -> schedule -> update."""
-    ef = state.ef
-    if ef is not None:
-        grads, st = compress_with_feedback(grads, compression.CompressionState(ef))
-        ef = st.error
-    grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-    lr = cosine_schedule(state.step, tcfg.warmup, tcfg.steps, tcfg.lr)
-    params, opt_state = opt.update(grads, state.opt, state.params, lr)
-    new_state = TrainState(params, opt_state, state.step + 1, ef)
+    """Shared trainer tail, under the named scope ``optimizer``: EF
+    compression -> clip -> schedule -> update."""
+    with jax.named_scope(obs.OPTIMIZER):
+        ef = state.ef
+        if ef is not None:
+            grads, st = compress_with_feedback(grads, compression.CompressionState(ef))
+            ef = st.error
+        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+        lr = cosine_schedule(state.step, tcfg.warmup, tcfg.steps, tcfg.lr)
+        params, opt_state = opt.update(grads, state.opt, state.params, lr)
+        new_state = TrainState(params, opt_state, state.step + 1, ef)
     metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
     return new_state, metrics
 

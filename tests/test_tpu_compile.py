@@ -5,17 +5,23 @@ refuses: unaligned sublane slices, VMEM overruns, Mosaic layout mismatches.
 These cases lower and compile forward, dgrad and wgrad against a described
 ``v5e:2x2`` topology - no chip attached, nothing runs - at the layer-1,
 layer-3, layer-13 and layer-14 shapes of a 416x416 input, on the 1x1 grid
-(the whole map) and on one tile of the 2x2 grid.
+(the whole map) and on one tile of the 2x2 grid. The whole YOLOv2-16 train
+step on the 2x2 grid compiles too, with each instruction under the named
+scopes of ``repro.obs`` (checked as ``test_tracing.py`` checks the 1x1 grid).
 """
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.kernels.conv2d_tiled.backward import conv2d_dgrad_tile, conv2d_wgrad_tile
 from repro.kernels.conv2d_tiled.kernel import conv2d_tile
+from repro import obs
+from repro.models.yolo import make_yolo_tiled_arch
+from test_tracing import check_scopes, compile_step, scopes_of
 
 BATCH = 8
 # YOLOv2-16 layer -> (input extent at 416x416, K, Cin, Cout)
@@ -62,3 +68,16 @@ def test_conv_kernel_compiles_for_v5e(one_chip, kind, layer, grid):
         args = (x, g)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("schedule", ["sync", "overlap"])
+def test_train_step_scopes_for_v5e_2x2(topo, schedule):
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("th", "tw"))
+    arch = make_yolo_tiled_arch((64, 64), 16, 2, 2, schedule=schedule, batch=4, mesh=mesh)
+    ops = compile_step(arch, 4)
+    seen = check_scopes(ops, arch.plan)
+    assert seen >= {"convolution", "select-and-scatter", "reduce-window", "all-reduce",
+                    "collective-permute", obs.OPTIMIZER}
+    # the halo exchanges of group inputs, forward and reversed in the backward
+    halo_layers = {scopes_of(n)[0] for op, n in ops if op.startswith("collective-permute")}
+    assert halo_layers <= {g.start for g in arch.plan.groups} and 0 in halo_layers
