@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.optim.compression import WireCodec, ef_encode
 
 
@@ -163,8 +164,25 @@ def halo_exchange_1d(
     shard and ``halo_hi`` rows from the next shard (zeros at the ends).
 
     Returns an array whose ``dim`` extent is ``x.shape[dim]+halo_lo+halo_hi``.
+
+    On an axis of one shard no tile has a neighbour, so the shifted
+    ``ppermute`` would deliver only zeros: the tile is zero-padded instead,
+    with no slice, collective or wire codec (and so no error-feedback
+    residual drawn from ``wire.bag``). XLA folds that pad into the consuming
+    conv's padding, and its transpose - a slice - into the data-gradient
+    conv's output window, where the exchange's concatenate and its
+    transposed slices cost full passes over the map. The pad is then the
+    conv's own SAME padding, so it is named under the ``conv`` scope: the
+    data-gradient conv that takes its name reads as conv work (DESIGN.md §15).
     """
+    if halo_lo == 0 and halo_hi == 0:
+        return x
     n = lax.axis_size(axis_name)
+    if n == 1:
+        cfg = [(0, 0, 0)] * x.ndim
+        cfg[dim] = (halo_lo, halo_hi, 0)
+        with jax.named_scope(obs.CONV):
+            return lax.pad(x, jnp.zeros((), x.dtype), cfg)
     parts = []
     if halo_lo > 0:
         # strip the *previous* shard must send us: its last halo_lo rows
@@ -176,8 +194,6 @@ def halo_exchange_1d(
         send_down = lax.slice_in_dim(x, 0, halo_hi, axis=dim)
         recv_hi = wire_shift(send_down, axis_name, _shift_perm(n, -1), wire)
         parts.append(recv_hi)
-    if len(parts) == 1:
-        return x
     return lax.concatenate(parts, dimension=dim)
 
 

@@ -7,7 +7,9 @@ These cases lower and compile forward, dgrad and wgrad against a described
 layer-3, layer-13 and layer-14 shapes of a 416x416 input, on the 1x1 grid
 (the whole map) and on one tile of the 2x2 grid. The whole YOLOv2-16 train
 step on the 2x2 grid compiles too, with each instruction under the named
-scopes of ``repro.obs`` (checked as ``test_tracing.py`` checks the 1x1 grid).
+scopes of ``repro.obs`` (checked as ``test_tracing.py`` checks the 1x1 grid),
+and so does the step on one chip's 1x1 grid, with no collective-permute:
+an axis of one shard zero-pads its halo instead of exchanging it.
 """
 import os
 
@@ -81,3 +83,12 @@ def test_train_step_scopes_for_v5e_2x2(topo, schedule):
     # the halo exchanges of group inputs, forward and reversed in the backward
     halo_layers = {scopes_of(n)[0] for op, n in ops if op.startswith("collective-permute")}
     assert halo_layers <= {g.start for g in arch.plan.groups} and 0 in halo_layers
+
+
+def test_train_step_1x1_for_v5e_has_no_collective_permute(topo):
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("th", "tw"))
+    arch = make_yolo_tiled_arch((64, 64), 16, 1, 1, batch=4, mesh=mesh)
+    ops = compile_step(arch, 4)
+    seen = check_scopes(ops, arch.plan)
+    assert seen >= {"convolution", "select-and-scatter", "reduce-window", obs.OPTIMIZER}
+    assert not [op for op, _ in ops if op.startswith("collective-permute")]
