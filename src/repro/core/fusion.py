@@ -62,8 +62,11 @@ from repro.core.spatial import (
     apply_layer_local,
     apply_layer_local_ragged,
     apply_layer_local_spec,
+    check_shortcuts,
     reshard_spatial_to_data,
     reshard_spatial_to_data_ragged,
+    shortcut_sources,
+    SkipStore,
     stack_reference,
 )
 from repro.core.grouping import (
@@ -80,6 +83,9 @@ from repro.core.grouping import (
     parse_cluster_spec,
     profile_cost,
     score_profile,
+    shortcut_group_error,
+    shortcut_pipeline_error,
+    shortcut_tail_error,
 )
 
 
@@ -185,6 +191,30 @@ class StackPlan:
         )
 
 
+def _check_shortcut_plan(layers, groups, map_hw, schedule, cross, pfirst) -> None:
+    """Plan-time refusals for stacks with shortcuts (DESIGN.md §16): the
+    uniform sync executor and the data tail run them; the overlap schedule
+    and pipeline tails do not, and a group edge or crossover may sit inside
+    a residual unit only where the skip needs no halo."""
+    if not shortcut_sources(layers):
+        return
+    check_shortcuts(layers, map_hw)
+    first = min(shortcut_sources(layers))
+    if schedule == "overlap":
+        raise ValueError(
+            f"shortcut layer {first} (from={layers[first].shortcut}): the "
+            "overlap schedule does not run shortcuts; use schedule='sync'"
+        )
+    if pfirst is not None:
+        raise ValueError(shortcut_pipeline_error(layers))
+    err = shortcut_tail_error(layers, cross)
+    for g in groups:
+        if err is None and g.mode == "spatial":
+            err = shortcut_group_error(layers, g.start, g.end)
+    if err:
+        raise ValueError(err)
+
+
 def resolve_hw_profile(hw: HardwareProfile | ClusterSpec | str | None):
     """Profile object from a profile, a ClusterSpec, a registered name, or
     None (Pi default)."""
@@ -241,6 +271,8 @@ def _resolve_crossover(
         return tuple(apply_crossover(groups, crossover))
     best = None
     for c in [None] + [g.start for g in groups]:
+        if shortcut_tail_error(layers, c):
+            continue
         cand = tuple(apply_crossover(groups, c))
         cost = score_profile(
             input_hw, layers, cand, n, m, hw, batch, schedule, mem_limit,
@@ -271,7 +303,8 @@ def _resolve_auto_schedule(
     uniform groups, and ragged groups run the sync exchange anyway."""
     from repro import compat
 
-    if isinstance(hw, ClusterSpec) or not compat.overlap_supported():
+    if (isinstance(hw, ClusterSpec) or not compat.overlap_supported()
+            or shortcut_sources(layers)):
         return "sync"
     cand = (
         tuple(groups)
@@ -487,6 +520,7 @@ def build_stack_plan(
     for l in layers:
         h, w = map_hw[-1]
         map_hw.append((l.out_extent(h), l.out_extent(w)))
+    _check_shortcut_plan(layers, groups, map_hw, schedule, cross, pfirst)
 
     # Resolve the tile partition over the spatial prefix (through the
     # first non-spatial layer's input; data- and pipeline-mode layers hold
@@ -574,7 +608,7 @@ def build_stack_plan(
             rem_halos[l] = (cur_lo, cur_hi, cur_lo, cur_hi)
         assert cur_lo == 0 and cur_hi == 0, "halo must be consumed by group end"
 
-    return StackPlan(
+    plan = StackPlan(
         layers=layers,
         groups=groups,
         n=n,
@@ -597,6 +631,14 @@ def build_stack_plan(
         wire_codec=wire_codec,
         inference=inference,
     )
+    if shortcut_sources(layers) and not plan.is_uniform:
+        l = min(shortcut_sources(layers))
+        raise ValueError(
+            f"shortcut layer {l} (from={layers[l].shortcut}): the ragged "
+            f"executors do not run shortcuts, and partition rows={tile_rows[0]} "
+            f"cols={tile_cols[0]} is not uniform; use an even partition"
+        )
+    return plan
 
 
 # ---------------------------------------------------------------------------
@@ -1026,9 +1068,17 @@ def apply_stack_local(
     (``_apply_group_ragged``, DESIGN.md §8); both run the sync exchange
     regardless of schedule, and the crossover goes through the ragged
     reshard.  Uniform plans take exactly the pre-partition code path.
+
+    Shortcuts (DESIGN.md §16): the outputs that later shortcuts read are
+    kept, each with the halo it carries, and dropped after their last
+    read.  A skip that is a group's input is kept as the exchanged (or,
+    past the crossover, resharded) tile, so a shortcut inside the group
+    crops it to its own halo.
     """
     bg = _global_batch(x.shape[0], batch_axis, batch_global)
     uniform = plan.is_uniform
+    skips = SkipStore(plan.layers)
+    skips.keep(-1, x, (0, 0, 0, 0))
     for gi, g in enumerate(plan.groups):
         if g.mode == "data":
             if gi == 0 or plan.groups[gi - 1].mode != "data":
@@ -1041,6 +1091,7 @@ def apply_stack_local(
                             plan.tile_rows[g.start], plan.tile_cols[g.start],
                             wire=wire,
                         )
+                skips.keep(g.start - 1, x, (0, 0, 0, 0))
             for l in g.layers:
                 with obs.layer_scope(l):
                     x = apply_layer_data(
@@ -1055,7 +1106,9 @@ def apply_stack_local(
                         batch_axis=batch_axis,
                         block_oh=plan.block_oh,
                         inference=plan.inference,
+                        skip=skips.read(l)[0],
                     )
+                skips.keep(l, x, (0, 0, 0, 0))
             continue
         if not uniform:
             group_fn = (
@@ -1095,7 +1148,9 @@ def apply_stack_local(
                 x = halo_exchange_2d(
                     x, plan.group_halos[gi], row_axis, col_axis, dims=(1, 2), wire=wire
                 )
+            skips.keep(g.start - 1, x, plan.group_halos[gi])
         for l in layers:
+            skip, skip_halo = skips.read(l)
             with obs.layer_scope(l):
                 x = apply_layer_local(
                     x,
@@ -1112,7 +1167,10 @@ def apply_stack_local(
                     batch_axis=batch_axis,
                     block_oh=plan.block_oh,
                     inference=plan.inference,
+                    skip=skip,
+                    skip_halo=skip_halo,
                 )
+            skips.keep(l, x, plan.rem_halos[l])
     return x
 
 

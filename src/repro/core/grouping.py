@@ -38,7 +38,7 @@ import dataclasses
 import re
 from typing import Sequence
 
-from repro.core.spatial import LayerDef, split_1d
+from repro.core.spatial import LayerDef, shortcut_sources, split_1d
 from repro.optim.compression import modeled_wire_bytes
 from repro.core.tiling import (
     Group,
@@ -660,7 +660,7 @@ def _group_cost(
         for idx in range(s, e + 1):
             l = layers[idx]
             oh, ow = ext[idx + 1]
-            if l.pool:
+            if not l.is_conv:
                 macs = oh * ow * max(l.in_channels, 1) * l.kernel * l.kernel
                 passes = 1.0
             else:
@@ -679,12 +679,14 @@ def _group_cost(
         k = idx - s
         ext_oh = oh // n + halo_lo[k + 1] + halo_hi[k + 1]
         ext_ow = ow // m + halo_lo[k + 1] + halo_hi[k + 1]
-        if l.pool:
+        # pools and shortcuts: one op per element of the window (a
+        # shortcut's is its add); their backward is a scatter or a copy
+        if not l.is_conv:
             macs = ext_oh * ext_ow * max(l.in_channels, 1) * l.kernel * l.kernel
         else:
             macs = ext_oh * ext_ow * l.kernel * l.kernel * l.in_channels * l.out_channels
         # fwd + delta backprop + weight grad ~= 3x fwd MACs (paper §4.1)
-        compute += (1.0 if l.pool else 3.0) * macs
+        compute += (3.0 if l.is_conv else 1.0) * macs
     compute_s = batch * compute / hw.flops
 
     ih, iw = ext[s]
@@ -705,7 +707,7 @@ def _group_cost(
         csp = split_1d(iw // m, halo_lo[0], halo_hi[0], lead.kernel, lead.stride)
         if rs is not None and csp is not None:
             int_area = (rs.i1 - rs.i0 + 1) * (csp.i1 - csp.i0 + 1)
-            if lead.pool:
+            if not lead.is_conv:
                 int_macs = int_area * max(lead.in_channels, 1) * lead.kernel ** 2
                 passes = 1.0
             else:
@@ -749,7 +751,7 @@ def _group_cost_cluster(
         for idx in range(s, e + 1):
             l = layers[idx]
             oh, ow = ext[idx + 1]
-            if l.pool:
+            if not l.is_conv:
                 macs = oh * ow * max(l.in_channels, 1) * l.kernel * l.kernel
                 passes = 1.0
             else:
@@ -770,7 +772,7 @@ def _group_cost_cluster(
                 k = idx - s
                 ext_oh = rows[idx + 1][i] + halo_lo[k + 1] + halo_hi[k + 1]
                 ext_ow = cols[idx + 1][j] + halo_lo[k + 1] + halo_hi[k + 1]
-                if l.pool:
+                if not l.is_conv:
                     macs += ext_oh * ext_ow * max(l.in_channels, 1) * l.kernel ** 2
                 else:
                     macs += (
@@ -814,6 +816,56 @@ def _group_halo_lohi(layers: Sequence[LayerDef], s: int, e: int) -> tuple[int, i
     return hl, hh
 
 
+def shortcut_group_error(layers: Sequence[LayerDef], s: int, e: int) -> str | None:
+    """Why spatial group [s, e] cannot run a shortcut it holds, or None
+    (DESIGN.md §16). A skip made inside the group, or the group's own
+    input, carries at least the halo the shortcut's output needs. A skip
+    made before the group's input was cut to its core at a group edge, so
+    the shortcut can read it only where the group carries no halo past it:
+    a group edge strictly inside a residual unit is refused unless every
+    layer after the shortcut in the group is halo-free."""
+    halo_lo, halo_hi = _halo_widths(layers, s, e)
+    for l, src in shortcut_sources(layers).items():
+        if not s <= l <= e or src >= s - 1:
+            continue
+        lo, hi = halo_lo[l + 1 - s], halo_hi[l + 1 - s]
+        if lo or hi:
+            return (
+                f"shortcut layer {l} (from={layers[l].shortcut}) adds layer "
+                f"{src}'s output, made before its group ({s}, {e}) starts, but "
+                f"the group carries a halo of ({lo}, {hi}) past it; start the "
+                f"group at layer {src + 1} or earlier, or end it at layer {l}"
+            )
+    return None
+
+
+def shortcut_tail_error(layers: Sequence[LayerDef], tail: int | None) -> str | None:
+    """Why a data tail starting at layer ``tail`` cannot run the shortcuts
+    it holds, or None: a skip made before the crossover's input is in the
+    spatial layout, so the crossover may sit only at a residual unit's edge."""
+    if tail is None:
+        return None
+    for l, src in shortcut_sources(layers).items():
+        if l >= tail and src < tail - 1:
+            return (
+                f"crossover at layer {tail} falls inside the residual unit of "
+                f"shortcut layer {l} (from={layers[l].shortcut}), whose skip "
+                f"comes from layer {src}; put the crossover at layer "
+                f"{src + 1} or earlier, or after layer {l}"
+            )
+    return None
+
+
+def shortcut_pipeline_error(layers: Sequence[LayerDef]) -> str | None:
+    """Pipeline tails do not run shortcuts: the message naming the first."""
+    for l in shortcut_sources(layers):
+        return (
+            f"shortcut layer {l} (from={layers[l].shortcut}): pipeline tails "
+            "do not run shortcuts; plan without a pipeline tail"
+        )
+    return None
+
+
 def _any_group_cost(
     layers, ext, tiles, s, e, n, m, hw, batch, schedule, mode="spatial",
     wire_codec="none",
@@ -832,7 +884,7 @@ def _filter_bytes(layers: Sequence[LayerDef], idxs, dtype_bytes: int) -> float:
     return sum(
         layers[i].kernel ** 2 * layers[i].in_channels * layers[i].out_channels * dtype_bytes
         for i in idxs
-        if not layers[i].pool
+        if layers[i].is_conv
     )
 
 
@@ -935,7 +987,7 @@ def _dense_macs3(layers: Sequence[LayerDef], ext, s: int, e: int) -> float:
     for idx in range(s, e + 1):
         l = layers[idx]
         oh, ow = ext[idx + 1]
-        if l.pool:
+        if not l.is_conv:
             macs += oh * ow * max(l.in_channels, 1) * l.kernel * l.kernel
         else:
             macs += 3.0 * oh * ow * l.kernel * l.kernel * l.in_channels * l.out_channels
@@ -1284,6 +1336,32 @@ def _spatial_group_mem(
     return act, halo
 
 
+def _skip_mem(
+    layers: Sequence[LayerDef], ext, g: Group, n: int, m: int,
+    batch: int, dtype_bytes: int, tiles=None,
+) -> float:
+    """Bytes of the skip maps of the shortcuts in group ``g`` on one device
+    (``peak_device_memory``'s ``skips``)."""
+    total = 0.0
+    if g.mode == "spatial":
+        halo_lo, halo_hi = _halo_widths(layers, g.start, g.end)
+    for l in shortcut_sources(layers):
+        if not g.start <= l <= g.end:
+            continue
+        c = max(layers[l].out_channels, 1) * dtype_bytes
+        h, w = ext[l + 1]
+        if g.mode != "spatial":
+            total += -(-batch // (n * m)) * h * w * c
+            continue
+        if tiles is not None:
+            h, w = max(tiles[0][l + 1]), max(tiles[1][l + 1])
+        else:
+            h, w = h // n, w // m
+        k = l + 1 - g.start
+        total += batch * (h + halo_lo[k] + halo_hi[k]) * (w + halo_lo[k] + halo_hi[k]) * c
+    return total
+
+
 def peak_device_memory(
     input_hw: tuple[int, int],
     layers: Sequence[LayerDef],
@@ -1323,6 +1401,11 @@ def peak_device_memory(
       Pipeline activations: a stage device stores its ceil(batch / P)
                    samples of the stage's own layer inputs (P = devices per
                    stage) - charged as the heaviest stage.
+      skips        one map per shortcut, at the extent of the shortcut's
+                   output (halo included in a spatial group): the skip is
+                   the stored input of the layer after its source, already
+                   counted, and its cotangent is held from the add's
+                   backward until the backward reaches the source.
     """
     ext = _map_extents(input_hw, layers)
     tiles = n * m
@@ -1334,9 +1417,10 @@ def peak_device_memory(
     )
     pipe_groups = [g for g in groups if g.mode == "pipeline"]
     stage_devs = tiles // len(pipe_groups) if pipe_groups else tiles
-    act = halo = 0.0
+    act = halo = skips = 0.0
     pipe_act_max = 0.0
     for g in groups:
+        skips += _skip_mem(layers, ext, g, n, m, batch, dtype_bytes, tiles_rc)
         if g.mode == "data":
             for idx in g.layers:
                 ih, iw = ext[idx]
@@ -1386,7 +1470,8 @@ def peak_device_memory(
         "halo": halo,
         "reshard_transient": reshard,
         "filters": filters,
-        "total": act + halo + reshard + filters,
+        "skips": skips,
+        "total": act + halo + reshard + filters + skips,
     }
 
 
@@ -1504,6 +1589,8 @@ def optimize_grouping(
     _check_schedule(schedule)
     L = len(layers)
     check_pipeline_arg(pipeline, n, m, L)
+    if pipeline is not None and shortcut_pipeline_error(layers):
+        raise ValueError(shortcut_pipeline_error(layers))
     ext = _map_extents(input_hw, layers)
     tiles_rc = None
     if isinstance(hw, ClusterSpec):
@@ -1542,6 +1629,8 @@ def optimize_grouping(
                     min(tiles_rc[0][s - 1]) < hmax or min(tiles_rc[1][s - 1]) < hmax
                 ):
                     continue
+            if shortcut_group_error(layers, s - 1, e - 1):
+                continue
             c, b, y, h = _any_group_cost(
                 layers, ext, tiles_rc, s - 1, e - 1, n, m, hw, batch, schedule,
                 wire_codec=wire_codec,
@@ -1603,7 +1692,7 @@ def optimize_grouping(
             candidates = [None if crossover == L else crossover]
         for c in candidates:
             prefix_len = L if c is None else c
-            if dp[prefix_len] == INF:
+            if dp[prefix_len] == INF or shortcut_tail_error(layers, c):
                 continue
             groups = backtrack(prefix_len)
             if c is not None:

@@ -54,7 +54,12 @@ from repro.core.backend import (
 
 @dataclasses.dataclass(frozen=True)
 class LayerDef:
-    """One conv or pool layer of a spatial stack."""
+    """One conv, pool or shortcut layer of a spatial stack.
+
+    A shortcut (``shortcut < 0``, darknet's ``[shortcut] from=``) adds the
+    output of the layer ``-shortcut`` places back to its input, the previous
+    layer's output; it is linear, has no parameters and no halo (K=1, S=1,
+    P=0). Build one with ``shortcut_layer``."""
 
     kernel: int
     stride: int = 1
@@ -65,6 +70,12 @@ class LayerDef:
     act: str = "leaky"
     use_bias: bool = True
     batch_norm: bool = False     # BN w/ exact cross-tile statistics
+    shortcut: int = 0            # darknet's from= offset (< 0); 0: not a shortcut
+
+    @property
+    def is_conv(self) -> bool:
+        """A layer with filters: neither a pool nor a shortcut."""
+        return not (self.pool or self.shortcut)
 
     @property
     def padding(self) -> int:
@@ -95,9 +106,47 @@ class LayerDef:
         return (h + 2 * self.padding - self.kernel) // self.stride + 1
 
 
+def shortcut_layer(channels: int, offset: int = -3) -> LayerDef:
+    """darknet's ``[shortcut] from=<offset> activation=linear``: the
+    previous layer's output plus the output ``-offset`` layers back."""
+    if offset >= 0:
+        raise ValueError(f"a shortcut reads an earlier layer: offset must be < 0, got {offset}")
+    return LayerDef(1, 1, channels, channels, pad=0, act="linear", use_bias=False,
+                    shortcut=offset)
+
+
+def shortcut_sources(layers: Sequence[LayerDef]) -> dict[int, int]:
+    """``{shortcut layer: the layer whose output it adds}``; -1 names the
+    stack input."""
+    return {i: i + l.shortcut for i, l in enumerate(layers) if l.shortcut}
+
+
+def check_shortcuts(layers: Sequence[LayerDef], map_hw: Sequence[tuple[int, int]]) -> None:
+    """Each shortcut adds a map of its input's shape, made at the same
+    resolution: the source exists, every layer between keeps the stride at
+    1, and extents and channels agree. ``map_hw[l]`` is layer l's input
+    extent."""
+    for l, src in shortcut_sources(layers).items():
+        name = f"shortcut layer {l} (from={layers[l].shortcut})"
+        if not -1 <= src < l:
+            raise ValueError(f"{name} reads layer {src}: an earlier layer or the stack input only")
+        src_ch = layers[0].in_channels if src < 0 else layers[src].out_channels
+        lay = layers[l]
+        if not (src_ch == lay.in_channels == lay.out_channels == layers[l - 1].out_channels):
+            raise ValueError(
+                f"{name} adds {src_ch} channels from layer {src} to "
+                f"{layers[l - 1].out_channels} from layer {l - 1}; they must agree"
+            )
+        if any(layers[k].stride != 1 for k in range(src + 1, l)) or map_hw[src + 1] != map_hw[l]:
+            raise ValueError(
+                f"{name} adds layer {src}'s {map_hw[src + 1]} map to layer "
+                f"{l - 1}'s {map_hw[l]}: the layers between must keep stride 1"
+            )
+
+
 def init_layer_params(key: jax.Array, layer: LayerDef, dtype=jnp.float32) -> dict:
-    """He-initialised conv params; empty dict for pools."""
-    if layer.pool:
+    """He-initialised conv params; empty dict for pools and shortcuts."""
+    if not layer.is_conv:
         return {}
     k = layer.kernel
     fan_in = k * k * layer.in_channels
@@ -169,12 +218,20 @@ def _bn_infer(y: jax.Array, params: dict, layer: LayerDef) -> jax.Array:
 
 
 def apply_layer_reference(
-    x: jax.Array, params: dict, layer: LayerDef, *, inference: bool = False
+    x: jax.Array,
+    params: dict,
+    layer: LayerDef,
+    *,
+    inference: bool = False,
+    skip: jax.Array | None = None,
 ) -> jax.Array:
     """Global (untiled) forward of one layer - the exactness oracle.
 
     ``inference=True`` applies BN from the frozen ``bn_mean``/``bn_var``
-    params (serving semantics) instead of the batch statistics."""
+    params (serving semantics) instead of the batch statistics. A shortcut
+    adds ``skip``, the output of its source layer."""
+    if layer.shortcut:
+        return x + skip
     p = layer.padding
     if layer.pool:
         return maxpool2d(x, layer.kernel, layer.stride, p)
@@ -198,9 +255,42 @@ def stack_reference(
     *,
     inference: bool = False,
 ) -> jax.Array:
-    for p, l in zip(params, layers):
-        x = apply_layer_reference(x, p, l, inference=inference)
+    skips = SkipStore(layers)
+    skips.keep(-1, x)
+    for i, (p, l) in enumerate(zip(params, layers)):
+        x = apply_layer_reference(x, p, l, inference=inference, skip=skips.read(i)[0])
+        skips.keep(i, x)
     return x
+
+
+class SkipStore:
+    """The outputs that the shortcuts of ``layers`` read, each with the halo
+    it carries, from the layer that makes them to their last read. A chain
+    keeps nothing."""
+
+    def __init__(self, layers: Sequence[LayerDef]):
+        self.sources = shortcut_sources(layers)
+        self.last: dict[int, int] = {}
+        for l, src in self.sources.items():
+            self.last[src] = max(self.last.get(src, l), l)
+        self.kept: dict[int, tuple] = {}
+
+    def keep(self, layer: int, x, halo=(0, 0, 0, 0)) -> None:
+        """Keep layer ``layer``'s output (-1: the stack input) if a later
+        shortcut reads it."""
+        if self.last.get(layer, layer) > layer:
+            self.kept[layer] = (x, halo)
+
+    def read(self, layer: int):
+        """``(skip, halo)`` for layer ``layer``, ``(None, zeros)`` unless it
+        is a shortcut; drops each skip after its last read."""
+        src = self.sources.get(layer)
+        if src is None:
+            return None, (0, 0, 0, 0)
+        skip = self.kept[src]
+        if self.last[src] == layer:
+            del self.kept[src]
+        return skip
 
 
 def freeze_bn_stats(
@@ -216,10 +306,13 @@ def freeze_bn_stats(
     In production the stats would instead be EMA running statistics
     accumulated during training; the inference executor only reads the two
     leaves, so either source works."""
-    out = []
-    for p, l in zip(params, layers):
+    out, skips = [], SkipStore(layers)
+    skips.keep(-1, x)
+    for i, (p, l) in enumerate(zip(params, layers)):
         p = dict(p)
-        if l.batch_norm and not l.pool:
+        if l.shortcut:
+            x = apply_layer_reference(x, p, l, skip=skips.read(i)[0])
+        elif l.batch_norm and not l.pool:
             y = conv2d_same(x, p["w"], l.stride, l.padding)
             if l.use_bias:
                 y = y + p["b"]
@@ -236,6 +329,7 @@ def freeze_bn_stats(
         else:
             x = apply_layer_reference(x, p, l)
         out.append(p)
+        skips.keep(i, x)
     return out
 
 
@@ -377,6 +471,8 @@ def apply_layer_local(
     batch_axis: str | None = None,
     block_oh: int | None = None,
     inference: bool = False,
+    skip: jax.Array | None = None,
+    skip_halo: tuple[int, int, int, int] = (0, 0, 0, 0),
 ) -> jax.Array:
     """One layer on a halo-extended local tile (input halo already present).
 
@@ -388,7 +484,13 @@ def apply_layer_local(
     activation the backend cannot fuse stay here, since BN needs cross-tile
     psums (over the batch mesh axis too, when one is present) - unless
     ``inference=True``, which swaps in the collective-free frozen-stats BN.
+
+    A shortcut adds ``skip``, its source's tile, which carries ``skip_halo``
+    around the same core and is cropped to ``out_halo`` (DESIGN.md §16).
+    Both addends are zero off the map, so the sum needs no mask.
     """
+    if layer.shortcut:
+        return add_shortcut(x, skip, skip_halo, out_halo)
     y, fused = _conv_or_pool(x, params, layer, backend, block_oh)
     return _finish_layer(
         y,
@@ -405,6 +507,23 @@ def apply_layer_local(
         batch_axis=batch_axis,
         inference=inference,
     )
+
+
+def add_shortcut(
+    x: jax.Array,
+    skip: jax.Array,
+    skip_halo: tuple[int, int, int, int],
+    out_halo: tuple[int, int, int, int],
+) -> jax.Array:
+    """``x + skip``, with ``skip`` first cropped from its halo to ``x``'s,
+    under the named scope ``shortcut``."""
+    with jax.named_scope(obs.SHORTCUT):
+        top, bottom, left, right = (s - o for s, o in zip(skip_halo, out_halo))
+        if min(top, bottom, left, right) < 0:
+            raise ValueError(
+                f"a skip with halo {skip_halo} cannot be cropped to {out_halo}"
+            )
+        return x + skip[:, top:skip.shape[1] - bottom, left:skip.shape[2] - right, :]
 
 
 @jax.custom_vjp
@@ -986,6 +1105,7 @@ def apply_layer_data(
     batch_axis: str | None = None,
     block_oh: int | None = None,
     inference: bool = False,
+    skip: jax.Array | None = None,
 ) -> jax.Array:
     """One data-mode layer: full (unhaloed) maps, batch shard per device.
 
@@ -994,7 +1114,10 @@ def apply_layer_data(
     anywhere in a data-mode layer.  BN still needs its cross-device psums:
     the tile axes now enumerate *batch shards*, so reducing over the same
     axes with the global ``batch x H x W`` count keeps statistics exact
-    (each (sample, position) is owned by exactly one device)."""
+    (each (sample, position) is owned by exactly one device).  A shortcut
+    adds ``skip``, its source's output in the same data layout."""
+    if layer.shortcut:
+        return add_shortcut(x, skip, (0, 0, 0, 0), (0, 0, 0, 0))
     with _compute_scope(layer):
         xp = pad_for_valid(x, layer.padding, pool=layer.pool)
     y, fused = _conv_or_pool(xp, params, layer, backend, block_oh)

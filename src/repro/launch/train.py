@@ -19,6 +19,10 @@ weight all-reduce).  ``--wire-codec int8|topk:<k>`` additionally compresses
 the per-sample collectives (halo strips, the reshard exchange, pipeline
 hand-offs) with error feedback on the recurring backward strips, and the
 planner prices its comm terms under the same codec (DESIGN.md §12).
+
+``--arch yolov3-tiled`` runs YOLOv3's Darknet-53 backbone (its first 62
+darknet sections: residual units with shortcuts, stride-2 downsampling)
+through the same path; ``--depth`` takes up to 62 there, 16 for YOLOv2.
 """
 from __future__ import annotations
 
@@ -40,10 +44,22 @@ from repro.runtime.driver import DriverConfig, DriverReport, run_training
 from repro.train.trainer import make_train_step
 
 TILED_ARCH = "yolov2-tiled"
+TILED_ARCHS = (TILED_ARCH, "yolov3-tiled")
+
+
+def _tiled_model(arch: str):
+    """``(layers(batch_norm=...), make_*_tiled_arch)`` of a tiled-CNN arch id."""
+    if arch == TILED_ARCH:
+        from repro.models.yolo import make_yolo_tiled_arch, yolov2_16_layers
+
+        return yolov2_16_layers, make_yolo_tiled_arch
+    from repro.models.yolov3 import make_yolov3_tiled_arch, yolov3_62_layers
+
+    return yolov3_62_layers, make_yolov3_tiled_arch
 
 
 def _add_args(ap: argparse.ArgumentParser) -> None:
-    ap.add_argument("--arch", choices=ARCH_IDS + [TILED_ARCH], default="stablelm-1.6b")
+    ap.add_argument("--arch", choices=ARCH_IDS + list(TILED_ARCHS), default="stablelm-1.6b")
     ap.add_argument("--full", action="store_true", help="full config (TPU-scale)")
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=8)
@@ -83,7 +99,9 @@ def _add_args(ap: argparse.ArgumentParser) -> None:
     # tiled-CNN (planner) options
     ap.add_argument("--grid", type=int, default=1, help="tiled: n=m tile grid")
     ap.add_argument("--input-hw", type=int, default=64, help="tiled: input H=W")
-    ap.add_argument("--depth", type=int, default=8, help="tiled: YOLO prefix depth")
+    ap.add_argument("--depth", type=int, default=8,
+                    help="tiled: prefix depth (up to 16 for yolov2-tiled, "
+                         "62 for yolov3-tiled)")
     ap.add_argument("--backend", default="xla", choices=["xla", "pallas"],
                     help="tiled: conv compute backend")
     ap.add_argument("--schedule", default="sync", choices=["sync", "overlap", "auto"],
@@ -180,9 +198,13 @@ def _driver_config(args) -> DriverConfig:
 
 def run_tiled(args) -> TiledRun:
     from repro.core.grouping import parse_cluster_spec
-    from repro.models.yolo import make_yolo_tiled_arch, yolov2_16_layers
 
-    n_layers = len(yolov2_16_layers()[: args.depth])
+    model_layers, make_arch = _tiled_model(args.arch)
+    n_layers = len(model_layers()[: args.depth])
+    if not 1 <= args.depth <= len(model_layers()):
+        raise SystemExit(
+            f"--depth {args.depth}: {args.arch} has {len(model_layers())} layers"
+        )
     cluster = (
         parse_cluster_spec(args.cluster, args.grid, args.grid)
         if args.cluster
@@ -190,7 +212,7 @@ def run_tiled(args) -> TiledRun:
     )
     hw = cluster if cluster is not None else args.hw_profile
     pipeline = _resolve_pipeline(args.pipeline)
-    arch = make_yolo_tiled_arch(
+    arch = make_arch(
         input_hw=(args.input_hw, args.input_hw),
         depth=args.depth,
         n=args.grid,
@@ -307,7 +329,7 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     enable_compile_cache()
 
-    if args.arch == TILED_ARCH:
+    if args.arch in TILED_ARCHS:
         run_tiled(args)
         return 0
 

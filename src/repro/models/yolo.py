@@ -111,17 +111,36 @@ def make_yolo_tiled_arch(
     bubble model (defaults to the planner's standard M).  ``wire_codec``
     (``"none" | "int8" | "topk:<k>"``; DESIGN.md §12) compresses the
     per-sample collectives and biases the planner's comm terms to match."""
+    return plan_tiled_arch(
+        yolov2_16_layers(batch_norm=batch_norm)[:depth], input_hw, n, m, groups,
+        backend=backend, schedule=schedule, hw=hw, batch=batch,
+        crossover=crossover, mem_limit=mem_limit, partition=partition,
+        pipeline=pipeline, microbatches=microbatches, wire_codec=wire_codec,
+        mesh=mesh, loss_local=loss_local,
+    )
+
+
+def plan_tiled_arch(
+    layers,
+    input_hw: tuple[int, int],
+    n: int,
+    m: int,
+    groups=None,
+    *,
+    microbatches: int | None = None,
+    mesh=None,
+    loss_local=l2_loss_local,
+    **plan_kw,
+) -> TiledCNNArch:
+    """Plan ``layers`` tiled n x m (``build_stack_plan``'s keywords in
+    ``plan_kw``) and bundle the plan with its mesh and loss for the trainer."""
     from repro.core.grouping import PIPELINE_MICROBATCHES
     from repro.launch.mesh import make_tile_mesh
 
-    layers = yolov2_16_layers(batch_norm=batch_norm)[:depth]
     plan = build_stack_plan(
         input_hw, layers, n, m, groups,
-        backend=backend, schedule=schedule, hw=hw, batch=batch,
-        crossover=crossover, mem_limit=mem_limit, partition=partition,
-        pipeline=pipeline,
         microbatches=PIPELINE_MICROBATCHES if microbatches is None else microbatches,
-        wire_codec=wire_codec,
+        **plan_kw,
     )
     return TiledCNNArch(
         plan=plan,

@@ -26,8 +26,9 @@ PLACE_BATCH = "arch.place_batch"
 
 # named scopes of the traced train step, inside a layer's scope or beside it
 CONV, BN, POOL, HALO, RESHARD = "conv", "bn", "pool", "halo", "reshard"
+SHORTCUT = "shortcut"
 LOSS, GRAD_SUM, OPTIMIZER = "loss", "grad_sum", "optimizer"
-SCOPES = (CONV, BN, POOL, HALO, RESHARD, LOSS, GRAD_SUM, OPTIMIZER)
+SCOPES = (CONV, BN, POOL, HALO, RESHARD, LOSS, GRAD_SUM, OPTIMIZER, SHORTCUT)
 
 
 def layer_scope(i: int):

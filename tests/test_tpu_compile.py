@@ -9,7 +9,10 @@ layer-3, layer-13 and layer-14 shapes of a 416x416 input, on the 1x1 grid
 step on the 2x2 grid compiles too, with each instruction under the named
 scopes of ``repro.obs`` (checked as ``test_tracing.py`` checks the 1x1 grid),
 and so does the step on one chip's 1x1 grid, with no collective-permute:
-an axis of one shard zero-pads its halo instead of exchanging it.
+an axis of one shard zero-pads its halo instead of exchanging it. The
+YOLOv3 backbone's 62-layer step compiles for one chip at 416x416 and the
+microbatch of 32 that ``yolov3-62.voc416.train.xla`` runs, with every
+convolution under ``conv`` and every shortcut's add under ``shortcut``.
 """
 import os
 
@@ -23,6 +26,7 @@ from repro.kernels.conv2d_tiled.backward import conv2d_dgrad_tile, conv2d_wgrad_
 from repro.kernels.conv2d_tiled.kernel import conv2d_tile
 from repro import obs
 from repro.models.yolo import make_yolo_tiled_arch
+from repro.models.yolov3 import make_yolov3_tiled_arch
 from test_tracing import check_scopes, compile_step, scopes_of
 
 BATCH = 8
@@ -92,3 +96,15 @@ def test_train_step_1x1_for_v5e_has_no_collective_permute(topo):
     seen = check_scopes(ops, arch.plan)
     assert seen >= {"convolution", "select-and-scatter", "reduce-window", obs.OPTIMIZER}
     assert not [op for op, _ in ops if op.startswith("collective-permute")]
+
+
+def test_yolov3_step_for_v5e_at_416(topo):
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("th", "tw"))
+    arch = make_yolov3_tiled_arch((416, 416), 62, 1, 1, batch=32, mesh=mesh)
+    ops = compile_step(arch, 32)
+    seen = check_scopes(ops, arch.plan)
+    assert seen >= {"convolution", obs.SHORTCUT, obs.OPTIMIZER}
+    conv_layers = {scopes_of(n)[0] for op, n in ops if op == "convolution"}
+    assert conv_layers == {i for i, l in enumerate(arch.plan.layers) if l.is_conv}
+    added = {scopes_of(n)[0] for op, n in ops if obs.SHORTCUT in scopes_of(n)[1]}
+    assert added == {i for i, l in enumerate(arch.plan.layers) if l.shortcut}

@@ -171,8 +171,14 @@ def check_scopes(ops, plan):
         if obs.OPTIMIZER in found:
             seen.add(obs.OPTIMIZER)
             assert layer is None, (opcode, name)
-        if set(found) & {obs.CONV, obs.BN, obs.POOL, obs.HALO}:
+        if set(found) & {obs.CONV, obs.BN, obs.POOL, obs.HALO, obs.SHORTCUT}:
             assert layer is not None, (opcode, name)
+        # a shortcut's add and crop sit under its own scope, in its own layer
+        if obs.SHORTCUT in found:
+            seen.add(obs.SHORTCUT)
+            assert found[-1:] == [obs.SHORTCUT] and plan.layers[layer].shortcut, (opcode, name)
+        if want == obs.CONV:
+            assert not plan.layers[layer].shortcut, (opcode, name)
     return seen
 
 
